@@ -18,12 +18,14 @@ from repro.ann.index import FilteredIndex, QueryBatch
 from repro.ann.methods import ALL_METHODS
 from repro.ann.predicates import Predicate
 from repro.ann.service import RouterService
+from repro.common import enable_compilation_cache
 from repro.core import training as T
 from repro.core.router import MLRouter
 from repro.data.ann_synth import DatasetSpec, synthesize, make_queries
 
 
 def main():
+    enable_compilation_cache()
     # 1. a small labelled vector dataset (Zipf labels over Gaussian clusters)
     spec = DatasetSpec("demo", 4000, 48, 64, 8, 12, 1.3, 2.0, 0.5, 0.3, 42)
     ds = synthesize(spec)

@@ -84,10 +84,11 @@ from repro.ann.service import (AsyncBatchQueue, RouterService,
 from repro.ann.sharded import ShardedFilteredIndex
 from repro.ann.store import MANIFEST, IndexStore
 from repro.ann import labels as lb
+from repro.common import enable_compilation_cache
 from repro.configs.base import get_smoke_config
 from repro.core import training as T
 from repro.data.ann_synth import DatasetSpec, synthesize
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import generate
 from repro.models import common, lm
 
@@ -188,6 +189,7 @@ def main():
                          "under artifacts/serve/, and install the "
                          "SIGUSR2/atexit post-mortem dumper")
     args = ap.parse_args()
+    enable_compilation_cache()
     if args.online_router:
         args.telemetry = True
     if args.slo:
@@ -298,7 +300,7 @@ def main():
     # --- served LM (reduced config; embeddings from its hidden states) ---
     cfg = get_smoke_config(args.arch)
     params = common.init_params(lm.model_desc(cfg), jax.random.PRNGKey(0))
-    ctx = lm.ModelCtx(mesh=make_mesh_compat((1, 1), ("data", "model")),
+    ctx = lm.ModelCtx(mesh=make_mesh((1, 1), ("data", "model")),
                       qc_prefill=32, gla_chunk=32)
     embed_fn = jax.jit(lambda p, b: lm.forward_prefill(p, b, cfg, ctx))
 

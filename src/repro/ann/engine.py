@@ -24,6 +24,8 @@ from repro.ann.dataset import ANNDataset
 from repro.ann.predicates import Predicate
 
 DEFAULT_QCHUNK = 64
+# bytes of gathered f32 candidate vectors one query chunk may materialise
+GATHER_BUDGET_BYTES = 1 << 30
 
 
 # ---------------------------------------------------------------------------
@@ -66,6 +68,7 @@ class DeviceData:
     vectors: jax.Array        # [N, d] f32
     norms: jax.Array          # [N] f32
     bitmaps: jax.Array        # [N, W] uint32
+    bitmaps_wm: jax.Array     # [W, N] uint32 word-major (the kernels' layout)
     group_bitmaps: jax.Array  # [G, W] uint32
     group_start: jax.Array    # [G] i32
     group_size: jax.Array     # [G] i32
@@ -140,6 +143,15 @@ def mask_cand(cand_bm: jax.Array, q_bm: jax.Array, pred_idx) -> jax.Array:
 # ---------------------------------------------------------------------------
 # query chunking
 # ---------------------------------------------------------------------------
+
+def gather_chunk(n_cand: int, dim: int) -> int:
+    """Queries per chunk for a search that gathers [chunk, n_cand, dim]
+    f32 candidate vectors: as many as fit `GATHER_BUDGET_BYTES`, between 1
+    and `DEFAULT_QCHUNK`. Sized in bytes, not candidate ids, so the gather
+    stays bounded at any embedding width."""
+    per_query = max(1, n_cand) * max(1, dim) * 4
+    return max(1, min(DEFAULT_QCHUNK, GATHER_BUDGET_BYTES // per_query))
+
 
 def run_chunked(fn, n_queries: int, *arrays, chunk: int = DEFAULT_QCHUNK,
                 extra_host=None):

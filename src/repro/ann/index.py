@@ -396,10 +396,12 @@ def _build_device_data(ds: ANNDataset) -> DeviceData:
     for j in range(g):
         s, l = int(ds.group_start[j]), int(ds.group_size[j])
         cent[j] = ds.vectors[s:s + l].mean(0)
+    bitmaps = jnp.asarray(ds.bitmaps)
     return DeviceData(
         vectors=jnp.asarray(ds.vectors),
         norms=jnp.asarray(ds.norms_sq),
-        bitmaps=jnp.asarray(ds.bitmaps),
+        bitmaps=bitmaps,
+        bitmaps_wm=bitmaps.T,
         group_bitmaps=jnp.asarray(ds.group_bitmaps),
         group_start=jnp.asarray(ds.group_start),
         group_size=jnp.asarray(ds.group_size),

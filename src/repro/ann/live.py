@@ -27,8 +27,9 @@ the base segment degrades gracefully until `compact()` folds the
 tombstones away, which is the intended cadence), then folds the base
 candidates and the delta segment through **one fused Pallas launch**
 (`ops.fused_live_topk`): the kernel scans the delta mirror block by
-block, applies the packed tombstone bitmap to *both* candidate sets
-in-kernel, and carries the running top-k in VMEM — no per-stage
+block, with the packed tombstone bitmap applied to *both* candidate
+sets on the device just before the launch, and carries the running
+top-k in VMEM — no per-stage
 overfetch on the delta, no `[S, Q, K]` HBM intermediate, no host merge.
 Once the delta outgrows `delta_prune_min_rows`, sealed chunks' mini-IVF
 indexes (`ChunkIndex`, built once at chunk-seal time) prune clusters
@@ -317,7 +318,8 @@ class DeltaSegment:
     private lock; `device_view` pads the partial tail chunk with
     sentinel rows (zero vector + `PAD_SCORE` norm — never selected by
     `masked_topk`) so the kernel sees shapes that change only at chunk
-    boundaries.
+    boundaries. The mirrored bitmaps are word-major ([W, rows]), the
+    layout the kernels read.
     """
 
     def __init__(self, dim: int, width: int, *,
@@ -329,7 +331,7 @@ class DeltaSegment:
         self._bm = np.empty((0, self.width), np.uint32)
         self._norms = np.empty((0,), np.float32)
         self._rows = 0
-        self._dev = None            # (vectors, norms, bitmaps) jax arrays
+        self._dev = None            # (vectors, norms, bitmaps_wm) jax arrays
         self._dev_rows = 0          # rows covered by the mirror
         self._dev_lock = threading.Lock()
         self._view_cache = None     # (rows, assembled device triple)
@@ -371,10 +373,10 @@ class DeltaSegment:
         return self._vec[:rows], self._bm[:rows], self._norms[:rows]
 
     def device_view(self, rows: int, scope):
-        """Device tensors covering the first `rows` rows, padded to a
-        chunk multiple with never-selected sentinel rows. `scope` is a
-        zero-arg context factory placing uploads (the owning handle's
-        `_device_scope`)."""
+        """Device (vectors [R, D], norms [R], bitmaps_wm [W, R]) covering
+        the first `rows` rows, padded to a chunk multiple R with
+        never-selected sentinel rows. `scope` is a zero-arg context
+        factory placing uploads (the owning handle's `_device_scope`)."""
         import jax.numpy as jnp
 
         from repro.kernels import masked_topk as mk
@@ -389,7 +391,7 @@ class DeltaSegment:
             if full > self._dev_rows:
                 with scope():
                     vec = jnp.asarray(self._vec[self._dev_rows: full])
-                    bm = jnp.asarray(self._bm[self._dev_rows: full])
+                    bm = jnp.asarray(self._bm[self._dev_rows: full].T)
                     nm = jnp.asarray(self._norms[self._dev_rows: full])
                     if self._dev is None:
                         self._dev = (vec, nm, bm)
@@ -397,19 +399,19 @@ class DeltaSegment:
                         self._dev = (
                             jnp.concatenate([self._dev[0], vec]),
                             jnp.concatenate([self._dev[1], nm]),
-                            jnp.concatenate([self._dev[2], bm]))
+                            jnp.concatenate([self._dev[2], bm], axis=1))
                 self._dev_rows = full
             dev = self._dev
         parts_v = [dev[0][:full]] if full else []
         parts_n = [dev[1][:full]] if full else []
-        parts_b = [dev[2][:full]] if full else []
+        parts_b = [dev[2][:, :full]] if full else []
         tail = rows - full
         if tail:
             tv = np.zeros((self.chunk, self.dim), np.float32)
-            tb = np.zeros((self.chunk, self.width), np.uint32)
+            tb = np.zeros((self.width, self.chunk), np.uint32)
             tn = np.full((self.chunk,), mk.PAD_SCORE, np.float32)
             tv[:tail] = self._vec[full:rows]
-            tb[:tail] = self._bm[full:rows]
+            tb[:, :tail] = self._bm[full:rows].T
             tn[:tail] = self._norms[full:rows]
             with scope():
                 parts_v.append(jnp.asarray(tv))
@@ -418,12 +420,12 @@ class DeltaSegment:
         if not parts_v:
             view = (jnp.zeros((0, self.dim), jnp.float32),
                     jnp.zeros((0,), jnp.float32),
-                    jnp.zeros((0, self.width), jnp.uint32))
+                    jnp.zeros((self.width, 0), jnp.uint32))
         elif len(parts_v) == 1:
             view = (parts_v[0], parts_n[0], parts_b[0])
         else:
             view = (jnp.concatenate(parts_v), jnp.concatenate(parts_n),
-                    jnp.concatenate(parts_b))
+                    jnp.concatenate(parts_b, axis=1))
         with self._dev_lock:
             # the row prefix below `rows` is immutable, so the view only
             # depends on the watermark — safe to reuse until it moves
@@ -1155,9 +1157,10 @@ class LiveFilteredIndex(_StableKeyMixin, _LabelClockMixin, _StageTimings):
                    snap: LiveSnapshot):
         """Single-launch live read: routed base candidates and the delta
         scan fold through one `ops.fused_live_topk` call; tombstones are
-        applied to both candidate sets in-kernel (packed-word gather),
-        so there is no host mask, no per-stage delta overfetch, and no
-        separate merge launch. Bit-identical to `_run_staged`."""
+        applied to both candidate sets on the device (packed-word gather
+        just before the launch), so there is no host mask, no per-stage
+        delta overfetch, and no separate merge launch. Bit-identical to
+        `_run_staged`."""
         import jax.numpy as jnp
 
         from repro.kernels import ops

@@ -81,6 +81,6 @@ class IVFGamma(engine.Method):
         fn = lambda qv, qb: _search(
             qv, qb, pred_idx, cent, cn, lists, dev.vectors, dev.norms,
             dev.bitmaps, nprobe=nprobe, k=k)
-        chunk = max(8, min(engine.DEFAULT_QCHUNK,
-                           (1 << 23) // max(1, nprobe * index.lists.shape[1])))
+        chunk = engine.gather_chunk(nprobe * index.lists.shape[1],
+                                   qvecs.shape[1])
         return engine.run_chunked(fn, qvecs.shape[0], qvecs, qbms, chunk=chunk)

@@ -100,7 +100,7 @@ class LabelNav(engine.Method):
             fn = lambda qv, qg: _search_eq(
                 qv, qg, dev.group_start, dev.group_size, dev.vectors,
                 dev.norms, maxg=maxg, k=k)
-            chunk = max(8, min(engine.DEFAULT_QCHUNK, (1 << 24) // maxg))
+            chunk = engine.gather_chunk(maxg, ds.dim)
             return engine.run_chunked(fn, nq, qvecs, qgroup, chunk=chunk)
 
         gc = min(int(search_params["group_cap"]), ds.n_groups)
@@ -110,5 +110,5 @@ class LabelNav(engine.Method):
             qv, qb, pred_idx, dev.group_bitmaps, dev.group_start,
             dev.group_size, dev.group_centroids, dev.group_cnorms,
             dev.vectors, dev.norms, group_cap=gc, per_group_cap=pgc, k=k)
-        chunk = max(8, min(engine.DEFAULT_QCHUNK, (1 << 24) // (gc * pgc)))
+        chunk = engine.gather_chunk(gc * pgc, ds.dim)
         return engine.run_chunked(fn, nq, qvecs, qbms, chunk=chunk)

@@ -90,6 +90,6 @@ class PostFilter(engine.Method):
         fn = lambda qv, qb: _search(
             qv, qb, pred_idx, cent, cn, lists, dev.vectors, dev.norms,
             dev.bitmaps, nprobe=nprobe, kprime=kprime, k=k)
-        chunk = max(8, min(engine.DEFAULT_QCHUNK,
-                           (1 << 24) // max(1, nprobe * index.lists.shape[1])))
+        chunk = engine.gather_chunk(nprobe * index.lists.shape[1],
+                                   qvecs.shape[1])
         return engine.run_chunked(fn, qvecs.shape[0], qvecs, qbms, chunk=chunk)

@@ -60,7 +60,7 @@ class PreFilter(engine.Method):
             from repro.kernels import ops
 
             fn = lambda qv, qb: ops.masked_topk(
-                qv, qb, dev.vectors, dev.norms, dev.bitmaps, pred=p, k=k)
+                qv, qb, dev.vectors, dev.norms, dev.bitmaps_wm, pred=p, k=k)
             return engine.run_chunked(fn, qvecs.shape[0], qvecs, qbms)
         pred_idx = jnp.int32(p)
         fn = lambda qv, qb: _search(qv, qb, pred_idx, dev.vectors,

@@ -158,8 +158,7 @@ class Sieve(engine.Method):
             fn = lambda qv, qb, cd: _scan_rows(
                 qv, qb, pred_idx, cd, dev.vectors, dev.norms, dev.bitmaps,
                 k=k, verify=verify)
-            chunk = max(8, min(engine.DEFAULT_QCHUNK,
-                               (1 << 24) // max(1, cand.shape[1])))
+            chunk = engine.gather_chunk(cand.shape[1], qvecs.shape[1])
             out[hit_idx], out_d[hit_idx] = engine.run_chunked(
                 fn, hit_idx.size, qvecs[hit_idx], qbms[hit_idx], cand,
                 chunk=chunk)
@@ -167,11 +166,14 @@ class Sieve(engine.Method):
         if miss_idx.size:
             ivf = index["ivf"]
             kprime = int(search_params.get("ef_search", 200))
+            nprobe = min(8, ivf.centroids.shape[0])
             fn = lambda qv, qb: _post_search(
                 qv, qb, pred_idx, fx.as_device(ivf.centroids),
                 fx.as_device(ivf.centroid_norms), fx.as_device(ivf.lists),
                 dev.vectors, dev.norms, dev.bitmaps,
-                nprobe=min(8, ivf.centroids.shape[0]), kprime=kprime, k=k)
+                nprobe=nprobe, kprime=kprime, k=k)
             out[miss_idx], out_d[miss_idx] = engine.run_chunked(
-                fn, miss_idx.size, qvecs[miss_idx], qbms[miss_idx])
+                fn, miss_idx.size, qvecs[miss_idx], qbms[miss_idx],
+                chunk=engine.gather_chunk(nprobe * ivf.lists.shape[1],
+                                          qvecs.shape[1]))
         return out, out_d

@@ -15,19 +15,23 @@ def artifacts_dir(*sub: str) -> str:
     return d
 
 
-_CACHE_ENABLED = False
+JAX_CACHE_DIR = os.path.join(_REPO_ROOT, "artifacts", "jax_cache")
 
 
 def enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache — big win for repeated CLI runs."""
-    global _CACHE_ENABLED
-    if _CACHE_ENABLED:
-        return
+    """Turn on JAX's persistent compilation cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no directory is set here. Otherwise the cache lives at the fixed
+    checkout path `JAX_CACHE_DIR`: the directory is part of what finds an
+    entry again, so it never follows ``REPRO_ARTIFACTS`` or anything that
+    changes from run to run."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", artifacts_dir("jax_cache"))
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(JAX_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    _CACHE_ENABLED = True
 
 
 @contextlib.contextmanager

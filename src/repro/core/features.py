@@ -321,7 +321,7 @@ def _base_selectivity(ds: ANNDataset, qbms: np.ndarray,
         # qbms is per-request: upload directly (the handle's as_device
         # cache would pin every batch forever)
         counts = ops.selectivity(jnp.asarray(qbms),
-                                 (fx or default_index(ds)).device.bitmaps,
+                                 (fx or default_index(ds)).device.bitmaps_wm,
                                  pred=int(pred))
         return np.asarray(counts).astype(np.float64) / ds.n
 

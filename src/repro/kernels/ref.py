@@ -1,9 +1,19 @@
-"""Pure-jnp oracles for the Pallas kernels (bitwise-identical semantics)."""
+"""Pure-jnp oracles for the Pallas kernels (bitwise-identical semantics).
+
+The oracles take the plain row-major [N, W] bitmaps, independent of the
+kernels' word-major layout, and score at full f32 matmul precision (a
+TPU's default f32 matmul rounds its inputs to bf16)."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+
+def _scores(qvecs, base, norms):
+    return norms[None, :].astype(jnp.float32) - 2.0 * jnp.dot(
+        qvecs, base.T, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def predicate_mask_ref(bitmaps, qbms, pred: int):
@@ -24,8 +34,7 @@ def masked_topk_ref(qvecs, qbms, base, norms, bitmaps, *, pred: int, k: int):
 
     k may exceed N (a delta segment smaller than the requested width):
     the candidate axis is padded so the surplus comes back as −1/+inf."""
-    scores = norms[None, :].astype(jnp.float32) - 2.0 * jnp.dot(
-        qvecs, base.T, preferred_element_type=jnp.float32)
+    scores = _scores(qvecs, base, norms)
     mask = predicate_mask_ref(bitmaps, qbms, pred)
     s = jnp.where(mask, scores, jnp.inf)
     if k > s.shape[1]:
@@ -50,8 +59,7 @@ def fused_live_topk_ref(qvecs, qbms, cand_ids, cand_dists, dvec, dnorms,
     fold order) and extract the k smallest. `tomb` is bool [n_total]."""
     nd = dvec.shape[0]
     d_ids = base_n + jnp.arange(nd, dtype=jnp.int32)
-    scores = dnorms[None, :].astype(jnp.float32) - 2.0 * jnp.dot(
-        qvecs, dvec.T, preferred_element_type=jnp.float32)
+    scores = _scores(qvecs, dvec, dnorms)
     mask = predicate_mask_ref(dbm, qbms, pred)
     live = ~tomb[jnp.clip(d_ids, 0, tomb.shape[0] - 1)]
     s = jnp.where(mask & live[None, :], scores, jnp.inf)

@@ -15,21 +15,17 @@ import dataclasses
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` across jax versions: newer releases take (and some
-    sharding modes need) `axis_types=Auto`; 0.4.x has no such kwarg."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis `Auto` (compiler-chosen sharding
+    propagation, which the model code's sharding rules assume)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 @dataclasses.dataclass(frozen=True)

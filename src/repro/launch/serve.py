@@ -36,10 +36,10 @@ def generate(params, cfg, prompts: list[list[int]], *, max_new: int,
 
     Note: all prompts must share one length for exact ring-buffer (Hymba)
     semantics; mixed lengths are fine for full-cache archs."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     ctx = ctx or lm.ModelCtx(
-        mesh=make_mesh_compat((1, 1), ("data", "model")),
+        mesh=make_mesh((1, 1), ("data", "model")),
         qc_prefill=64, gla_chunk=64)
     lens_set = {len(p) for p in prompts}
     assert len(lens_set) == 1, \

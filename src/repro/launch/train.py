@@ -34,9 +34,9 @@ def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
                log_every: int = 10, lr: float = 3e-4, seed: int = 0,
                mesh=None, resume: bool = True, accum: int = 1,
                deadline_s: float | None = None, verbose: bool = True):
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = mesh or make_mesh_compat((1, 1), ("data", "model"))
+    mesh = mesh or make_mesh((1, 1), ("data", "model"))
     dp_axes = tuple(n for n in mesh.axis_names if n != "model")
     ctx = lm.ModelCtx(mesh=mesh, dp_axes=dp_axes,
                       tp_size=mesh.shape["model"],

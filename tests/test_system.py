@@ -97,12 +97,12 @@ def test_rag_serve_path(tiny_ds, tiny_index):
     from repro.models import common, lm
     from repro.ann import labels as lb
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     _, _, router = _artifacts()
     cfg = get_smoke_config("qwen2-0.5b")
     params = common.init_params(lm.model_desc(cfg), jax.random.PRNGKey(0))
-    ctx = lm.ModelCtx(mesh=make_mesh_compat((1, 1), ("data", "model")),
+    ctx = lm.ModelCtx(mesh=make_mesh((1, 1), ("data", "model")),
                       qc_prefill=16, gla_chunk=16)
     toks = jnp.ones((2, 16), jnp.int32)
     with ctx.mesh:
