@@ -1,0 +1,9 @@
+"""Mean `route` span (features, MLP forward, Algorithm 2) per batch of
+the closed loop, in ms, from the program's tracer."""
+
+
+def read(ctx):
+    if ctx.kind != "closed" or not ctx.spans or "route" not in ctx.spans:
+        return None
+    h = ctx.spans["route"]
+    return h["sum_us"] / h["count"] / 1e3 if h["count"] else None
