@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.ann import trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.predicates import Predicate
 
@@ -153,17 +154,10 @@ def gather_chunk(n_cand: int, dim: int) -> int:
     return max(1, min(DEFAULT_QCHUNK, GATHER_BUDGET_BYTES // per_query))
 
 
-def run_chunked(fn, n_queries: int, *arrays, chunk: int = DEFAULT_QCHUNK,
-                extra_host=None):
-    """Run `fn(chunked_arrays..., extra_host_chunk...)` over fixed-size query
-    chunks; pads the tail chunk; returns np.concatenate of outputs.
-
-    arrays: per-query arrays, leading axis Q. extra_host: same, but kept as
-    numpy (for host-side lookups already resolved to per-query values).
-    `fn` may return a single array or a tuple of per-query arrays — tuple
-    outputs are concatenated position-wise (e.g. (ids, dists)).
-    """
-    outs = []
+def padded_chunks(n_queries: int, arrays, chunk: int):
+    """(real rows, padded parts) of each fixed-size query chunk of the
+    per-query `arrays` (leading axis Q); the tail chunk repeats its last
+    query up to `chunk` rows, so every chunk has one static shape."""
     for s in range(0, n_queries, chunk):
         e = min(s + chunk, n_queries)
         pad = chunk - (e - s)
@@ -173,22 +167,38 @@ def run_chunked(fn, n_queries: int, *arrays, chunk: int = DEFAULT_QCHUNK,
             if pad:
                 part = np.concatenate([part, np.repeat(part[-1:], pad, axis=0)], axis=0)
             parts.append(part)
-        hparts = []
-        if extra_host is not None:
-            for a in extra_host:
-                part = a[s:e]
-                if pad:
-                    part = np.concatenate([part, np.repeat(part[-1:], pad, axis=0)], axis=0)
-                hparts.append(part)
-        res = fn(*parts, *hparts)
-        if isinstance(res, tuple):
-            outs.append(tuple(np.asarray(r)[: e - s] for r in res))
-        else:
-            outs.append(np.asarray(res)[: e - s])
+        yield e - s, parts
+
+
+def concat_chunks(outs: list):
+    """np.concatenate of per-chunk outputs; tuple outputs position-wise."""
     if isinstance(outs[0], tuple):
         return tuple(np.concatenate([o[i] for o in outs], axis=0)
                      for i in range(len(outs[0])))
     return np.concatenate(outs, axis=0)
+
+
+def run_chunked(fn, n_queries: int, *arrays, chunk: int = DEFAULT_QCHUNK):
+    """Run `fn(chunked_arrays...)` over fixed-size query chunks; pads the
+    tail chunk; returns np.concatenate of outputs.
+
+    arrays: per-query arrays, leading axis Q.
+    `fn` may return a single array or a tuple of per-query arrays — tuple
+    outputs are concatenated position-wise (e.g. (ids, dists)).
+
+    Each chunk's launch and the host's wait for its result is one
+    `trace.launch` span of `chunk` slots, the tail chunk's padding
+    counted as `pad_slots`.
+    """
+    outs = []
+    for rows, parts in padded_chunks(n_queries, arrays, chunk):
+        with trace.launch(chunk, chunk - rows):
+            res = fn(*parts)
+            if isinstance(res, tuple):
+                outs.append(tuple(np.asarray(r)[:rows] for r in res))
+            else:
+                outs.append(np.asarray(res)[:rows])
+    return concat_chunks(outs)
 
 
 # ---------------------------------------------------------------------------

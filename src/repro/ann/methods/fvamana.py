@@ -14,7 +14,7 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.ann import engine, graph, topk
+from repro.ann import engine, graph, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.labels import unpack_one
 from repro.ann.predicates import Predicate
@@ -76,6 +76,9 @@ class FVamana(engine.Method):
                 seeds[qi, 1 + j] = index.label_entry[l]
 
         nbrs = fx.as_device(index.neighbors)
+        # seeds, then one expanded node's neighbours per beam iteration
+        trace.count("cand_rows", nq * (self.MAX_SEEDS
+                                       + l_search * index.neighbors.shape[1]))
 
         def fn(qv, qb, sd):
             pool_ids, pool_d = graph.beam_search(

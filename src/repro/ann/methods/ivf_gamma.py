@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ann import engine, topk
+from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.ivf import IVFIndex, build_ivf, graft_ivf
 from repro.ann.predicates import Predicate
@@ -75,6 +75,8 @@ class IVFGamma(engine.Method):
         dev = fx.device
         pred_idx = jnp.int32(int(Predicate(pred)))
         nprobe = min(4 * int(search_params["gamma"]), index.centroids.shape[0])
+        trace.count("cand_rows",
+                    qvecs.shape[0] * nprobe * index.lists.shape[1])
         cent = fx.as_device(index.centroids)
         cn = fx.as_device(index.centroid_norms)
         lists = fx.as_device(index.lists)

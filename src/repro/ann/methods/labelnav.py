@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ann import engine, topk
+from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.predicates import Predicate
 
@@ -97,6 +97,7 @@ class LabelNav(engine.Method):
                 [ds.group_id_of_bitmap(qbms[i]) for i in range(nq)],
                 dtype=np.int32)
             maxg = max(8, index["maxg"])
+            trace.count("cand_rows", nq * maxg)
             fn = lambda qv, qg: _search_eq(
                 qv, qg, dev.group_start, dev.group_size, dev.vectors,
                 dev.norms, maxg=maxg, k=k)
@@ -105,6 +106,7 @@ class LabelNav(engine.Method):
 
         gc = min(int(search_params["group_cap"]), ds.n_groups)
         pgc = int(search_params["per_group_cap"])
+        trace.count("cand_rows", nq * gc * pgc)
         pred_idx = jnp.int32(int(pred))
         fn = lambda qv, qb: _search_sub(
             qv, qb, pred_idx, dev.group_bitmaps, dev.group_start,

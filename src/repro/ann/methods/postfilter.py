@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ann import engine, topk
+from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.ivf import IVFIndex, build_ivf, graft_ivf
 from repro.ann.predicates import Predicate
@@ -87,6 +87,8 @@ class PostFilter(engine.Method):
         cn = fx.as_device(index.centroid_norms)
         lists = fx.as_device(index.lists)
         nprobe = min(nprobe, index.centroids.shape[0])
+        trace.count("cand_rows",
+                    qvecs.shape[0] * nprobe * index.lists.shape[1])
         fn = lambda qv, qb: _search(
             qv, qb, pred_idx, cent, cn, lists, dev.vectors, dev.norms,
             dev.bitmaps, nprobe=nprobe, kprime=kprime, k=k)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ann import engine, topk
+from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.predicates import Predicate
 
@@ -54,6 +54,7 @@ class PreFilter(engine.Method):
                search_params: dict):
         dev = fx.device
         p = int(Predicate(pred))
+        trace.count("cand_rows", qvecs.shape[0] * dev.vectors.shape[0])
         use_kernel = (jax.default_backend() == "tpu"
                       if self.use_kernel is None else self.use_kernel)
         if use_kernel:

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ann import engine, topk
+from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.ivf import IVFIndex, build_ivf
 from repro.ann.methods.postfilter import _search as _post_search
@@ -158,6 +158,7 @@ class Sieve(engine.Method):
             fn = lambda qv, qb, cd: _scan_rows(
                 qv, qb, pred_idx, cd, dev.vectors, dev.norms, dev.bitmaps,
                 k=k, verify=verify)
+            trace.count("cand_rows", hit_idx.size * cand.shape[1])
             chunk = engine.gather_chunk(cand.shape[1], qvecs.shape[1])
             out[hit_idx], out_d[hit_idx] = engine.run_chunked(
                 fn, hit_idx.size, qvecs[hit_idx], qbms[hit_idx], cand,
@@ -167,6 +168,8 @@ class Sieve(engine.Method):
             ivf = index["ivf"]
             kprime = int(search_params.get("ef_search", 200))
             nprobe = min(8, ivf.centroids.shape[0])
+            trace.count("cand_rows",
+                        miss_idx.size * nprobe * ivf.lists.shape[1])
             fn = lambda qv, qb: _post_search(
                 qv, qb, pred_idx, fx.as_device(ivf.centroids),
                 fx.as_device(ivf.centroid_norms), fx.as_device(ivf.lists),

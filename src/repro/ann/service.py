@@ -6,7 +6,7 @@ serves typed `QueryBatch` → `SearchResult` traffic.
   features + stacked-MLP + array-op Algorithm 2), then execute each
   chosen (method, ps) group as one batched search on the owned index.
 * `search_chunked()` — the same pipeline micro-batched over fixed-size
-  query chunks via `engine.run_chunked` (bounded per-chunk memory and
+  query chunks (`engine.padded_chunks`; bounded per-chunk memory and
   latency for serving).
 * `explain()` — per-query routing transparency: predicted recall r̂ per
   candidate, the threshold-passing set, the chosen (method, ps), and the
@@ -117,11 +117,13 @@ class RouterService:
               t: float | None = None) -> list[RoutingDecision]:
         """Per-query `RoutingDecision`s without executing the searches
         (Algorithm 2 at threshold `t`, default the service's)."""
-        with trace.span("route", q=batch.q):
+        with trace.span("route", q=batch.q) as sp:
             r_hat = self.predict(batch)
-            decisions = self._decide(r_hat, batch, t)
-            trace.annotate(table_version=getattr(
-                self.router.table, "version", None))
+            with trace.span("route.decide"):
+                decisions = self._decide(r_hat, batch, t)
+            if sp is not None:
+                sp.set(table_version=getattr(
+                    self.router.table, "version", None))
             return decisions
 
     def _decide(self, r_hat, batch, t):
@@ -216,10 +218,12 @@ class RouterService:
         if callable(pop):
             timings.update(pop())
         generation = getattr(self.index, "generation", 0)
-        trace.annotate(
-            decisions=sorted({f"{m}/{ps}" for (m, ps) in groups}),
-            generation=int(generation),
-            table_version=getattr(self.router.table, "version", None))
+        sp = trace.current()
+        if sp is not None:
+            sp.set(decisions=sorted({f"{m}/{ps}" for (m, ps) in groups}),
+                   generation=int(generation),
+                   table_version=getattr(self.router.table, "version",
+                                         None))
         sink = self.telemetry
         if sink is not None:
             sink.record_batch(
@@ -258,11 +262,11 @@ class RouterService:
                     slo_state=(slo_eng.state() if slo_eng is not None
                                else None)):
                 olog.emit(ev)
-        return SearchResult(
-            ids=ids,
-            distances=exact_distances(raw, ids, batch.vectors),
-            decisions=list(decisions),
-            timings=timings, keys=keys)
+        with trace.span("distances"):
+            dists = exact_distances(raw, ids, batch.vectors)
+        return SearchResult(ids=ids, distances=dists,
+                            decisions=list(decisions), timings=timings,
+                            keys=keys)
 
     def search(self, batch: QueryBatch, *,
                t: float | None = None) -> SearchResult:
@@ -281,6 +285,7 @@ class RouterService:
         """
         with trace.maybe_trace(self.tracer, "search", q=batch.q,
                                k=batch.k, pred=int(batch.pred)):
+            trace.count("queries", batch.q)
             t0 = time.perf_counter()
             decisions = self.route(batch, t=t)
             t1 = time.perf_counter()
@@ -295,7 +300,7 @@ class RouterService:
                        chunk: int = engine.DEFAULT_QCHUNK,
                        t: float | None = None) -> SearchResult:
         """`search` micro-batched over fixed-size query chunks via
-        `engine.run_chunked` (static shapes per chunk; the serving
+        `engine.padded_chunks` (static shapes per chunk; the serving
         entry point for steady traffic).
 
         `chunk` bounds the routing/result granularity; methods still pad
@@ -304,7 +309,9 @@ class RouterService:
         work for latency, not memory."""
         timings = {"route_s": 0.0, "search_s": 0.0, "total_s": 0.0}
 
-        def fn(qv, qb):
+        outs = []
+        for rows, (qv, qb) in engine.padded_chunks(
+                batch.q, (batch.vectors, batch.bitmaps), chunk):
             res = self.search(
                 QueryBatch(qv, qb, batch.pred, batch.k), t=t)
             for key, val in res.timings.items():
@@ -315,10 +322,9 @@ class RouterService:
             dec[:] = res.decisions
             keys = (res.keys if res.keys is not None
                     else np.full(res.ids.shape, -1, np.int64))
-            return res.ids, res.distances, dec, keys
-
-        ids, dists, dec, keys = engine.run_chunked(
-            fn, batch.q, batch.vectors, batch.bitmaps, chunk=chunk)
+            outs.append(tuple(a[:rows] for a in
+                              (res.ids, res.distances, dec, keys)))
+        ids, dists, dec, keys = engine.concat_chunks(outs)
         return SearchResult(ids=ids, distances=dists,
                             decisions=list(dec), timings=timings,
                             keys=keys)

@@ -19,6 +19,15 @@ in order:
    per-span latency histograms update for *every* trace regardless of
    the sampling verdict, so `/metrics` stays unbiased.
 
+4. **One clock with the device.**  While a trace is active, every
+   span opened with :func:`span` (and every root of
+   :meth:`Tracer.trace`) also holds a ``jax.profiler.TraceAnnotation``
+   named ``repro.<span name>``, so a JAX profile shows the program's
+   layers on the same timeline as the device ops.  :func:`launch`
+   spans mark the host waiting on the device; :meth:`Tracer.histograms`
+   sums, per span name, the host time outside them (``host_us``) and
+   the :func:`count` totals of each subtree (``counters``).
+
 Exports render a finished tree as Chrome-trace/Perfetto JSON
 (:func:`perfetto_json`) — overlapping siblings (parallel shard fan-out)
 are pushed onto separate ``tid`` lanes so every lane is properly
@@ -41,6 +50,7 @@ __all__ = [
     "Span",
     "Tracer",
     "span",
+    "launch",
     "annotate",
     "count",
     "attach",
@@ -54,6 +64,13 @@ __all__ = [
 
 _ACTIVE: "contextvars.ContextVar[Span | None]" = contextvars.ContextVar(
     "repro_ann_active_span", default=None)
+
+# The span that wraps one device launch and the host wait for its
+# result; `Tracer` subtracts these from each span's host time.
+LAUNCH = "launch"
+
+# jax.profiler, imported on the first span opened under a trace
+_profiler = None
 
 # Attribute keys hoisted from any span of a kept tree into the flight
 # record's flat ``annotations`` dict (first writer wins).
@@ -84,7 +101,7 @@ class Span:
     the owner closes stragglers at :meth:`Tracer.finish`."""
 
     __slots__ = ("name", "t0", "t1", "attrs", "children", "error",
-                 "trace_id")
+                 "trace_id", "counted")
 
     def __init__(self, name: str, attrs: dict | None = None,
                  t0: float | None = None):
@@ -95,6 +112,7 @@ class Span:
         self.children: list[Span] = []
         self.error: str | None = None
         self.trace_id: str | None = None
+        self.counted: set | None = None   # attrs written by `count`
 
     # -- construction ------------------------------------------------------
     def child(self, name: str, *, t0: float | None = None,
@@ -160,14 +178,31 @@ class Span:
 # Ambient-context API (no-ops outside an active trace)
 # ---------------------------------------------------------------------------
 
+def _profiler_annotation(name: str, attrs: dict, trace_id=None):
+    """Enter a ``repro.<name>`` profiler annotation carrying the span's
+    scalar attributes (and the root's trace id) as metadata."""
+    global _profiler
+    if _profiler is None:
+        from jax import profiler
+        _profiler = profiler
+    meta = {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+    if trace_id is not None:
+        meta["trace_id"] = trace_id
+    ann = _profiler.TraceAnnotation("repro." + name, **meta)
+    ann.__enter__()
+    return ann
+
+
 class _SpanCtx:
-    __slots__ = ("_name", "_attrs", "_span", "_token")
+    __slots__ = ("_name", "_attrs", "_span", "_token", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self._name = name
         self._attrs = attrs
         self._span: Span | None = None
         self._token = None
+        self._ann = None
 
     def __enter__(self) -> Span | None:
         parent = _ACTIVE.get()
@@ -178,6 +213,7 @@ class _SpanCtx:
         parent.children.append(s)
         self._span = s
         self._token = _ACTIVE.set(s)
+        self._ann = _profiler_annotation(self._name, self._attrs)
         return s
 
     def __exit__(self, et, ev, tb) -> bool:
@@ -187,6 +223,7 @@ class _SpanCtx:
         if et is not None and s.error is None:
             s.error = f"{et.__name__}: {ev}"
         s.finish()
+        self._ann.__exit__(None, None, None)
         _ACTIVE.reset(self._token)
         return False
 
@@ -195,6 +232,24 @@ def span(name: str, **attrs) -> _SpanCtx:
     """Open a child span under the ambient trace; no-op (yields ``None``)
     when no trace is active, so call sites need no enabled-check."""
     return _SpanCtx(name, attrs)
+
+
+class _LaunchCtx(_SpanCtx):
+    __slots__ = ()
+
+    def __enter__(self) -> Span | None:
+        s = super().__enter__()
+        if s is not None:
+            s.counted = set(s.attrs)
+        return s
+
+
+def launch(slots: int, pad_slots: int = 0) -> _SpanCtx:
+    """A `launch` span: one device launch plus the host's wait for its
+    result, counting ``launches`` (1), ``slots`` (the query rows it
+    launched) and ``pad_slots`` (how many of them are padding)."""
+    return _LaunchCtx(LAUNCH, {"launches": 1, "slots": int(slots),
+                               "pad_slots": int(pad_slots)})
 
 
 def current() -> Span | None:
@@ -218,10 +273,15 @@ def annotate(**attrs) -> None:
 
 
 def count(name: str, n: int = 1) -> None:
-    """Increment a numeric attribute on the innermost active span."""
+    """Increment a numeric attribute on the innermost active span; the
+    tracer totals it per span name over each subtree (`counters`)."""
     s = _ACTIVE.get()
     if s is not None:
         s.attrs[name] = s.attrs.get(name, 0) + n
+        if s.counted is None:
+            s.counted = {name}
+        else:
+            s.counted.add(name)
 
 
 class _Attach:
@@ -250,7 +310,7 @@ def attach(s: Span | None) -> _Attach:
 
 
 class _RootCtx:
-    __slots__ = ("_tracer", "_name", "_attrs", "_root", "_token")
+    __slots__ = ("_tracer", "_name", "_attrs", "_root", "_token", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -258,11 +318,14 @@ class _RootCtx:
         self._attrs = attrs
         self._root: Span | None = None
         self._token = None
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._root = Span(self._name, self._attrs)
         self._root.trace_id = self._tracer.new_trace_id()
         self._token = _ACTIVE.set(self._root)
+        self._ann = _profiler_annotation(self._name, self._attrs,
+                                         self._root.trace_id)
         return self._root
 
     def __exit__(self, et, ev, tb) -> bool:
@@ -270,6 +333,8 @@ class _RootCtx:
         root = self._root
         if et is not None and root.error is None:
             root.error = f"{et.__name__}: {ev}"
+        root.finish()
+        self._ann.__exit__(None, None, None)
         self._tracer.finish(root)
         return False
 
@@ -333,6 +398,54 @@ class LatencyHistogram:
 
 
 # ---------------------------------------------------------------------------
+# Per-layer totals of one tree: host time outside launches, subtree counts
+# ---------------------------------------------------------------------------
+
+def _covered(iv: list, lo: float, hi: float) -> float:
+    """Length of the union of intervals `iv`, clipped to [lo, hi]."""
+    total, end = 0.0, lo
+    for a, b in sorted(iv):
+        a, b = max(a, end), min(b, hi)
+        if b > a:
+            total += b - a
+            end = b
+    return total
+
+
+def _layer_totals(root: Span) -> tuple[dict, dict]:
+    """Per span name: µs of each span outside the union of its `launch`
+    descendants (``host``), and the totals of every `count` made in each
+    span's subtree (``counts``). The root's counts cover its tree."""
+    host: dict[str, float] = {}
+    counts: dict[str, dict[str, float]] = {}
+
+    def visit(s: Span) -> tuple[list, dict]:
+        iv: list = []
+        tot: dict = {}
+        for c in s.children:
+            civ, ctot = visit(c)
+            iv += civ
+            for k, v in ctot.items():
+                tot[k] = tot.get(k, 0) + v
+        for k in s.counted or ():
+            tot[k] = tot.get(k, 0) + s.attrs[k]
+        if s.name == LAUNCH:
+            inside, iv = 0.0, [(s.t0, s.t1)]
+        else:
+            inside = _covered(iv, s.t0, s.t1)
+        host[s.name] = (host.get(s.name, 0.0)
+                        + max(0.0, s.t1 - s.t0 - inside) * 1e6)
+        if tot:
+            agg = counts.setdefault(s.name, {})
+            for k, v in tot.items():
+                agg[k] = agg.get(k, 0) + v
+        return iv, tot
+
+    visit(root)
+    return host, counts
+
+
+# ---------------------------------------------------------------------------
 # Tracer: sampling, flight recorder, histograms
 # ---------------------------------------------------------------------------
 
@@ -357,6 +470,8 @@ class Tracer:
         self._recent: deque[Span] = deque(maxlen=int(recent_capacity))
         self._flight: deque[dict] = deque(maxlen=int(flight_capacity))
         self._hist: dict[str, LatencyHistogram] = {}
+        self._host_us: dict[str, float] = {}
+        self._counts: dict[str, dict[str, float]] = {}
         self._seq = itertools.count()
         self._rng = random.Random(seed)
         # separate stream for ids: drawing them from the sampling rng
@@ -403,6 +518,7 @@ class Tracer:
                     annot[k] = s.attrs[k]
         dur_ms = root.duration_s * 1e3
         slow = self.slow_ms is not None and dur_ms >= self.slow_ms
+        host, counts = _layer_totals(root)
         with self._lock:
             c = self._counters
             c["traces"] += 1
@@ -411,6 +527,12 @@ class Tracer:
                 if h is None:
                     h = self._hist[s.name] = LatencyHistogram()
                 h.observe(s.duration_s * 1e6)
+            for name, us in host.items():
+                self._host_us[name] = self._host_us.get(name, 0.0) + us
+            for name, tot in counts.items():
+                agg = self._counts.setdefault(name, {})
+                for k, v in tot.items():
+                    agg[k] = agg.get(k, 0) + v
             if err is not None:
                 c["errors"] += 1
             if slow:
@@ -439,13 +561,18 @@ class Tracer:
         with self._lock:
             out = dict(self._counters)
             out["flight_size"] = len(self._flight)
-            out["span_p50_us"] = {n: h.quantile_us(0.5)
-                                  for n, h in self._hist.items()}
         return out
 
     def histograms(self) -> dict:
+        """Per span name: the latency histogram's snapshot, plus
+        ``host_us`` (summed time outside `launch` descendants: the host
+        working while the device waits) and ``counters`` (summed `count`
+        totals of each span's subtree)."""
         with self._lock:
-            return {n: h.snapshot() for n, h in self._hist.items()}
+            return {n: {**h.snapshot(),
+                        "host_us": self._host_us.get(n, 0.0),
+                        "counters": dict(self._counts.get(n, {}))}
+                    for n, h in self._hist.items()}
 
     def recent(self) -> list[Span]:
         with self._lock:
@@ -461,6 +588,8 @@ class Tracer:
             self._recent.clear()
             self._flight.clear()
             self._hist.clear()
+            self._host_us.clear()
+            self._counts.clear()
             for k in self._counters:
                 self._counters[k] = 0
 

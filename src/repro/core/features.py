@@ -30,6 +30,7 @@ import dataclasses
 import numpy as np
 
 from repro.ann import labels as lb
+from repro.ann import trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.predicates import Predicate
 
@@ -320,10 +321,12 @@ def _base_selectivity(ds: ANNDataset, qbms: np.ndarray,
 
         # qbms is per-request: upload directly (the handle's as_device
         # cache would pin every batch forever)
-        counts = ops.selectivity(jnp.asarray(qbms),
-                                 (fx or default_index(ds)).device.bitmaps_wm,
-                                 pred=int(pred))
-        return np.asarray(counts).astype(np.float64) / ds.n
+        with trace.launch(qbms.shape[0]):
+            counts = ops.selectivity(
+                jnp.asarray(qbms), (fx or default_index(ds)).device.bitmaps_wm,
+                pred=int(pred))
+            counts = np.asarray(counts)
+        return counts.astype(np.float64) / ds.n
 
     # queries repeat label sets heavily (they are drawn from base vectors):
     # evaluate unique bitmaps once and scatter the results back

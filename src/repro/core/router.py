@@ -28,6 +28,7 @@ import os
 
 import numpy as np
 
+from repro.ann import trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.predicates import Predicate
 from repro.core import features as F
@@ -108,8 +109,10 @@ class MLRouter:
         feature pass + one stacked-MLP forward for the whole batch).
         `fx`: the caller's owned `FilteredIndex`, so the TPU feature
         kernel reuses its device tensors instead of the default pool."""
-        x = F.feature_matrix(ds, qbms, pred, self.feature_names, fx=fx)
-        return self.predict_recalls_from_features(x)
+        with trace.span("route.features"):
+            x = F.feature_matrix(ds, qbms, pred, self.feature_names, fx=fx)
+        with trace.span("route.mlp"):
+            return self.predict_recalls_from_features(x)
 
     def retrained(self, models: dict, scaler: "mlp.Scaler",
                   table: BenchmarkTable | None = None) -> "MLRouter":
@@ -131,8 +134,10 @@ class MLRouter:
 
     def predict_recalls_from_features(self, x_raw: np.ndarray) -> np.ndarray:
         xs = self.scaler.transform(x_raw)
-        out = mlp.forward_stacked(self.stacked_params(), xs)   # [M, Q, 1]
-        return np.asarray(out[:, :, 0]).T.astype(np.float32)   # [Q, M]
+        with trace.launch(xs.shape[0]):
+            out = mlp.forward_stacked(self.stacked_params(), xs)   # [M, Q, 1]
+            out = np.asarray(out[:, :, 0])
+        return out.T.astype(np.float32)                        # [Q, M]
 
     # ---- Algorithm 2 ------------------------------------------------------
     def route_from_predictions(self, r_hat: np.ndarray, ds_name: str,

@@ -10,7 +10,10 @@ the shared monotonic clock, and the stage spans the pipeline promises
 even when route and execute ran on different worker threads.
 """
 
+import glob
 import json
+import os
+import sys
 import threading
 import time
 import urllib.request
@@ -18,7 +21,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from repro.ann import trace
+from repro.ann import engine, trace
 from repro.ann.index import QueryBatch
 from repro.ann.live import LiveFilteredIndex
 from repro.ann.metrics import MetricsServer, metrics_text
@@ -114,6 +117,137 @@ def test_maybe_trace_nests_instead_of_double_rooting():
     assert [c.name for c in root.children] == ["inner"]
     with trace.maybe_trace(None, "off") as s:  # no tracer, no ambient
         assert s is None
+
+
+class _RecordingAnnotation:
+    """Stands in for `jax.profiler.TraceAnnotation`: records each build
+    and checks that every entered annotation is left on its thread."""
+
+    built: list = []
+    open_: dict = {}
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+        _RecordingAnnotation.built.append((name, meta))
+
+    def __enter__(self):
+        key = threading.get_ident()
+        _RecordingAnnotation.open_.setdefault(key, []).append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        stack = _RecordingAnnotation.open_[threading.get_ident()]
+        assert stack.pop() == self.name
+        return False
+
+
+@pytest.fixture
+def recorded_annotations(monkeypatch):
+    import jax.profiler
+
+    _RecordingAnnotation.built = []
+    _RecordingAnnotation.open_ = {}
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        _RecordingAnnotation)
+    yield _RecordingAnnotation
+    assert not any(_RecordingAnnotation.open_.values())
+
+
+def test_profiler_annotation_per_span_only_under_a_trace(
+        recorded_annotations):
+    with trace.span("orphan", q=1):           # no trace: nothing built
+        trace.count("launches")
+    assert recorded_annotations.built == []
+    tr = Tracer(sample=1.0)
+    with tr.trace("search", q=4, pred=1) as root:
+        with trace.span("group", method="sieve", ps="b1", q=2,
+                        rows=np.int64(3), bits=[1, 2]):
+            with trace.launch(64, 8):
+                pass
+        root.child("enqueue_wait", t0=root.t0, t1=root.t0)  # after the fact
+    tr.finish(tr.start("detached"))
+    names = [n for n, _ in recorded_annotations.built]
+    assert names == ["repro.search", "repro.group", "repro.launch"]
+    meta = dict(recorded_annotations.built)
+    assert meta["repro.search"] == {"q": 4, "pred": 1,
+                                    "trace_id": root.trace_id}
+    # scalar attributes only: numpy scalars and lists stay in the tracer
+    assert meta["repro.group"] == {"method": "sieve", "ps": "b1", "q": 2}
+    assert meta["repro.launch"] == {"launches": 1, "slots": 64,
+                                    "pad_slots": 8}
+    h = tr.histograms()
+    assert h["search"]["counters"] == {"launches": 1, "slots": 64,
+                                       "pad_slots": 8}
+
+
+def _hand_span(parent, name, t0, t1, **counts):
+    s = parent.child(name, t0=t0, t1=t1)
+    with trace.attach(s):
+        for k, v in counts.items():
+            trace.count(k, v)
+    return s
+
+
+def test_host_time_and_counters_on_a_hand_built_tree():
+    """search [0, 10]: route [0, 4] holds launches [1, 2] and [1.5, 3]
+    (overlapping: their union is 2); execute [4, 10] holds a group
+    [4, 9] with a launch [5, 8], and a distances span [9, 10] with no
+    launch."""
+    tr = Tracer(sample=1.0)
+    root = tr.start("search")
+    root.t0 = 0.0
+    with trace.attach(root):
+        trace.count("queries", 8)
+    route = _hand_span(root, "route", 0.0, 4.0)
+    _hand_span(route, trace.LAUNCH, 1.0, 2.0, launches=1, slots=8,
+               pad_slots=0)
+    _hand_span(route, trace.LAUNCH, 1.5, 3.0, launches=1, slots=8,
+               pad_slots=0)
+    execute = _hand_span(root, "execute", 4.0, 10.0)
+    group = _hand_span(execute, "group", 4.0, 9.0, cand_rows=96)
+    _hand_span(group, trace.LAUNCH, 5.0, 8.0, launches=1, slots=16,
+               pad_slots=8)
+    _hand_span(execute, "distances", 9.0, 10.0)
+    root.t1 = 10.0
+    tr.finish(root)
+    h = tr.histograms()
+    us = {n: h[n]["host_us"] for n in h}
+    assert us["route"] == pytest.approx(2e6)         # 4 − union 2
+    assert us["group"] == pytest.approx(2e6)         # 5 − 3
+    assert us["execute"] == pytest.approx(3e6)       # 6 − 3
+    assert us["distances"] == pytest.approx(1e6)
+    assert us["search"] == pytest.approx(5e6)        # 10 − 2 − 3
+    assert us[trace.LAUNCH] == pytest.approx(1e6 + 1.5e6 + 3e6)
+    assert h["search"]["counters"] == {"queries": 8, "launches": 3,
+                                       "slots": 32, "pad_slots": 8,
+                                       "cand_rows": 96}
+    assert h["route"]["counters"] == {"launches": 2, "slots": 16,
+                                      "pad_slots": 0}
+    assert h["group"]["counters"] == {"cand_rows": 96, "launches": 1,
+                                      "slots": 16, "pad_slots": 8}
+    assert h[trace.LAUNCH]["counters"] == {"launches": 3, "slots": 32,
+                                           "pad_slots": 8}
+    assert h["distances"]["counters"] == {}
+    assert root.find("group").attrs["cand_rows"] == 96   # still attrs
+    tr.clear()
+    assert tr.histograms() == {}
+
+
+@pytest.mark.parametrize("n, launches, pad", [(256, 4, 0), (100, 2, 28)])
+def test_run_chunked_counts_launches_and_padding(n, launches, pad):
+    tr = Tracer(sample=1.0)
+    q = np.arange(n * 3, dtype=np.float32).reshape(n, 3)
+    with tr.trace("search"):
+        out = engine.run_chunked(lambda qv: qv * 2, n, q)
+    np.testing.assert_array_equal(out, q * 2)
+    h = tr.histograms()
+    assert h["search"]["counters"] == {"launches": launches,
+                                       "slots": launches * 64,
+                                       "pad_slots": pad}
+    assert h[trace.LAUNCH]["count"] == launches
+    # untraced: the same answer and no tracer work
+    np.testing.assert_array_equal(
+        engine.run_chunked(lambda qv: qv * 2, n, q), q * 2)
 
 
 # ----------------------------------------------------- sampling policy
@@ -297,6 +431,61 @@ def test_service_search_traces_route_and_execute(tiny_ds, tiny_index,
     # RoutingDecision + table/generation provenance on the record
     assert rec["annotations"]["decisions"]
     assert "generation" in rec["annotations"]
+
+
+def test_profile_nests_program_spans_inside_the_harness_span(
+        tmp_path, tiny_ds, tiny_index, toy_router, tiny_queries):
+    """A JAX profile (CPU) of one traced search holds the program's spans
+    as `repro.*` host annotations, nested on the profiler's one clock:
+    bench.search ⊃ repro.search ⊃ repro.route ⊃ repro.launch and
+    repro.search ⊃ repro.execute ⊃ repro.group ⊃ repro.launch."""
+    import jax
+    from jax.profiler import ProfileData
+
+    root_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root_dir not in sys.path:
+        sys.path.insert(0, root_dir)
+    from bench import trace_reduce
+
+    tracer = Tracer(sample=1.0)
+    svc = _routed(tiny_index, toy_router, tracer)
+    qs = tiny_queries[Predicate.OR]
+    batch = QueryBatch(qs.vectors[:6], qs.bitmaps[:6], Predicate.OR, 5)
+    svc.search(batch)                # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.search"):
+            svc.search(batch)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    planes = trace_reduce.flatten(ProfileData.from_file(path))
+    evs = [(name, s, s + d) for p in planes
+           if not p["name"].startswith(trace_reduce.DEVICE_PREFIX)
+           for ln in p["lines"] for name, s, d in ln["events"]
+           if name.startswith(("bench.", "repro."))]
+
+    def inside(name, outer):
+        return [e for e in evs if e[0] == name
+                and outer[1] <= e[1] and e[2] <= outer[2]]
+
+    bench_search, = [e for e in evs if e[0] == "bench.search"]
+    search, = inside("repro.search", bench_search)
+    route, = inside("repro.route", search)
+    execute, = inside("repro.execute", search)
+    assert route[2] <= execute[1]
+    assert inside("repro.route.features", route)
+    mlp, = inside("repro.route.mlp", route)
+    assert inside("repro.launch", mlp)
+    assert inside("repro.route.decide", route)
+    groups = inside("repro.group", execute)
+    assert groups and all(inside("repro.launch", g) for g in groups)
+    assert inside("repro.distances", execute)
+    # every program span of the search, and nothing more, is annotated
+    h = tracer.histograms()
+    assert sum(h[n]["count"] for n in h) == 2 * len(
+        [e for e in evs if e[0].startswith("repro.")])
 
 
 def test_queue_traces_well_formed_across_thread_hops(tiny_ds, tiny_index,
