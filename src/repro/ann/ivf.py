@@ -18,6 +18,9 @@ class IVFIndex:
     centroid_norms: np.ndarray  # [nlist] float32
     lists: np.ndarray           # [nlist, max_list] int32, −1 pad
     list_len: np.ndarray        # [nlist] int32
+    # [N] int32, each row's list (−1: none); filled by ivf_gamma, whose
+    # scan reads it, from `lists` and never persisted
+    row_list: np.ndarray | None = None
 
 
 def kmeans(x: np.ndarray, k: int, iters: int = 8, seed: int = 0,

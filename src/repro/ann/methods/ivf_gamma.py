@@ -6,10 +6,19 @@ The TPU-native counterpart: probe γ× more IVF lists than the unfiltered
 baseline would and apply the predicate mask **in-scan**, so every candidate
 that reaches top-k already satisfies the filter. γ trades compute for
 recall uniformly across predicate types.
+
+The probed lists are scored by one masked scan of the whole base per
+64-query chunk (`ops.ivf_scan_topk`): a row is a candidate when its list
+is one of the query's probed lists and it passes the predicate. The
+queries of a chunk share each read of the base and score on the MXU. A
+gather of the probed lists' padded rows was slower on a TPU v5e at every
+γ and every batch size, one query included: its launches are padded to
+a chunk of queries too, and gathered rows score on the VPU.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 import jax
@@ -20,21 +29,45 @@ from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.ivf import IVFIndex, build_ivf, graft_ivf
 from repro.ann.predicates import Predicate
+from repro.kernels import ops
 
 
-@partial(jax.jit, static_argnames=("nprobe", "k"))
-def _search(qvecs, qbms, pred_idx, centroids, cnorms, lists,
-            vectors, norms, bitmaps, *, nprobe: int, k: int):
-    nq = qvecs.shape[0]
+def probed_lists(qvecs, centroids, cnorms, nprobe: int):
+    """[Q, nprobe] ids of the lists each query probes: its nearest
+    centroids."""
     cd = topk.score_all(qvecs, centroids, cnorms)
-    _, probe = jax.lax.top_k(-cd, nprobe)
-    cand = lists[probe].reshape(nq, -1)                        # [Q, C]
-    cvec = vectors[jnp.maximum(cand, 0)]
-    cn = norms[jnp.maximum(cand, 0)]
-    d = topk.score_candidates(qvecs, cvec, cn)
-    cbm = bitmaps[jnp.maximum(cand, 0)]                        # [Q, C, W]
-    ok = engine.mask_cand(cbm, qbms, pred_idx) & (cand >= 0)
-    return topk.topk_ids(d, cand, k, valid=ok)
+    return jax.lax.top_k(-cd, nprobe)[1]
+
+
+def row_lists(lists: np.ndarray, n: int) -> np.ndarray:
+    """[n] int32: the list that holds each row, −1 for a row no list holds
+    (one a `max_list_cap` dropped)."""
+    out = np.full(n, -1, dtype=np.int32)
+    rows_c, _ = np.nonzero(lists >= 0)
+    out[lists[lists >= 0]] = rows_c
+    return out
+
+
+def _with_row_list(index: IVFIndex, n: int) -> IVFIndex:
+    return dataclasses.replace(index, row_list=row_lists(index.lists, n))
+
+
+@partial(jax.jit, static_argnames=("nprobe", "k", "pred"))
+def _scan(qvecs, qbms, centroids, cnorms, list_len, row_list,
+          vectors, norms, bitmaps_wm, *, nprobe: int, k: int, pred: int):
+    """Filtered top-k over each query's `nprobe` nearest lists, by a masked
+    scan of the whole base. Also returns each query's rows in its probed
+    lists (list padding left out)."""
+    nq, nlist = qvecs.shape[0], centroids.shape[0]
+    probe = probed_lists(qvecs, centroids, cnorms, nprobe)
+    hit = jnp.zeros((nq, -(-nlist // 32) * 32), jnp.uint32).at[
+        jnp.arange(nq)[:, None], probe].set(1)
+    words = jnp.sum(hit.reshape(nq, -1, 32)
+                    << jnp.arange(32, dtype=jnp.uint32), axis=2,
+                    dtype=jnp.uint32)                          # [Q, P]
+    ids, dists = ops.ivf_scan_topk(qvecs, qbms, words, vectors, norms,
+                                   bitmaps_wm, row_list, pred=pred, k=k)
+    return ids, dists, jnp.sum(list_len[probe], axis=1)
 
 
 class IVFGamma(engine.Method):
@@ -49,8 +82,9 @@ class IVFGamma(engine.Method):
         ]
 
     def build(self, ds: ANNDataset, build_params: dict) -> IVFIndex:
-        return build_ivf(ds.vectors, int(build_params.get("nlist", 128)),
-                         seed=13)
+        return _with_row_list(
+            build_ivf(ds.vectors, int(build_params.get("nlist", 128)),
+                      seed=13), ds.n)
 
     def index_arrays(self, index: IVFIndex) -> dict:
         return {"centroids": index.centroids,
@@ -62,27 +96,30 @@ class IVFGamma(engine.Method):
         return IVFIndex(centroids=arrays["centroids"],
                         centroid_norms=arrays["centroid_norms"],
                         lists=arrays["lists"],
-                        list_len=arrays["list_len"])
+                        list_len=arrays["list_len"],
+                        row_list=row_lists(arrays["lists"], ds.n))
 
     def graft_index(self, new_ds: ANNDataset, old_index: IVFIndex,
                     old_ds: ANNDataset, old_to_new, new_rows, build_params):
         if old_index.centroids.shape[0] == 0 or new_ds.n == 0:
             return None
-        return graft_ivf(old_index, new_ds.vectors, old_to_new)
+        return _with_row_list(
+            graft_ivf(old_index, new_ds.vectors, old_to_new), new_ds.n)
 
     def search(self, fx, index: IVFIndex, qvecs, qbms, pred: Predicate,
                k: int, search_params: dict):
         dev = fx.device
-        pred_idx = jnp.int32(int(Predicate(pred)))
+        nq = qvecs.shape[0]
         nprobe = min(4 * int(search_params["gamma"]), index.centroids.shape[0])
-        trace.count("cand_rows",
-                    qvecs.shape[0] * nprobe * index.lists.shape[1])
+        trace.count("cand_rows", nq * dev.vectors.shape[0])
+        trace.count("scan_queries", nq)
         cent = fx.as_device(index.centroids)
         cn = fx.as_device(index.centroid_norms)
-        lists = fx.as_device(index.lists)
-        fn = lambda qv, qb: _search(
-            qv, qb, pred_idx, cent, cn, lists, dev.vectors, dev.norms,
-            dev.bitmaps, nprobe=nprobe, k=k)
-        chunk = engine.gather_chunk(nprobe * index.lists.shape[1],
-                                   qvecs.shape[1])
-        return engine.run_chunked(fn, qvecs.shape[0], qvecs, qbms, chunk=chunk)
+        list_len = fx.as_device(index.list_len)
+        row_list = fx.as_device(index.row_list)
+        fn = lambda qv, qb: _scan(
+            qv, qb, cent, cn, list_len, row_list, dev.vectors, dev.norms,
+            dev.bitmaps_wm, nprobe=nprobe, k=k, pred=int(Predicate(pred)))
+        ids, dists, rows = engine.run_chunked(fn, nq, qvecs, qbms)
+        trace.count("probe_rows", int(rows.sum()))
+        return ids, dists

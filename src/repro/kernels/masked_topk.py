@@ -33,6 +33,11 @@ The same VMEM-carried accumulation (factored as `_fold_topk`) also powers
 per-shard [S, Q, K] top-k candidates are folded shard by shard into one
 global [Q, KP] result, with shards as the sequential grid axis.
 
+`ivf_scan_accum` is the same scan with a second mask — a row counts only
+if its IVF list is one of the query's probed lists — so an IVF search
+whose gather would read as many rows as the base scores them on the MXU
+instead, with the queries of a tile sharing each read of the base.
+
 The fused live kernel takes no tombstone table: the wrapper applies the
 tombstones in XLA before the launch (dead base candidates become PAD
 slots, dead delta rows get id −1), so the kernel only ever reads
@@ -239,6 +244,91 @@ def masked_topk_accum(qvecs, qbms, base, norms, bitmaps_wm, *, pred: int,
         interpret=interpret,
         name="masked_topk_accum",
     )(qvecs, qbms, base, norms, bitmaps_wm)
+    return outd, outi
+
+
+# ---------------------------------------------------------------------------
+# probe-masked scan — IVF search as a dense scan over the probed lists' rows
+# ---------------------------------------------------------------------------
+
+def _probe_mask_block(row_list, probe):
+    """row_list [1, BN] int32 (each row's IVF list, −1 for none), probe
+    [BQ, P] uint32 (bit ``l & 31`` of word ``l >> 5`` set for each list
+    l the query probes) -> bool [BQ, BN]: the row lies in a probed list.
+
+    Each row's word is picked by P compares, then tested at its bit. Only
+    basic slices are taken, as in `_predicate_mask_block`, so the XLA
+    twin runs the same code."""
+    word = row_list >> 5                                    # −1 -> −1
+    bit = jnp.left_shift(jnp.uint32(1), (row_list & 31).astype(jnp.uint32))
+    words = None
+    for j in range(probe.shape[1]):
+        pw = jnp.where(word == j, probe[:, j:j + 1], jnp.uint32(0))
+        words = pw if words is None else words | pw
+    return (words & bit) != 0
+
+
+def _ivf_scan_kernel(q_ref, qbm_ref, probe_ref, base_ref, norms_ref, bm_ref,
+                     rl_ref, outd_ref, outi_ref, accd_ref, acci_ref, *,
+                     pred: int, k: int, bn: int):
+    """`_accum_kernel` with a second mask: a row is a candidate only if
+    its IVF list is one of the query's probed lists."""
+    pid_n = pl.program_id(1)
+
+    @pl.when(pid_n == 0)
+    def _init():
+        _init_carry(accd_ref, acci_ref)
+
+    scores = _scores(q_ref[...], base_ref[...], norms_ref[...])
+    mask = (_predicate_mask_block(bm_ref, qbm_ref, pred)
+            & _probe_mask_block(rl_ref[...], probe_ref))
+    s = jnp.where(mask, scores, PAD_SCORE)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    ids_blk = jnp.where(s >= PAD_SCORE, -1, col + pid_n * bn)
+    _fold_topk(accd_ref, acci_ref, s, ids_blk, k)
+
+    @pl.when(pid_n == pl.num_programs(1) - 1)
+    def _write():
+        _write_carry(accd_ref, acci_ref, outd_ref, outi_ref)
+
+
+def ivf_scan_accum(qvecs, qbms, probe, base, norms, bitmaps_wm, row_list, *,
+                   pred: int, k: int, bq: int = DEFAULT_BQ,
+                   bn: int = DEFAULT_BN, interpret: bool = False):
+    """Raw pallas_call: `masked_topk_accum` restricted to the rows of each
+    query's probed IVF lists.
+
+    Operands as `masked_topk_accum`, plus probe [Q, P] uint32 (the
+    probed lists as a bitmap over list ids) and row_list [1, N] int32
+    (each row's list, −1 for a row in no list). Output: dists [Q, KP]
+    f32, ids [Q, KP] i32, PAD_SCORE / −1 past k.
+    """
+    q, d = qvecs.shape
+    w, n = bitmaps_wm.shape
+    p = probe.shape[1]
+    assert q % bq == 0 and n % bn == 0 and bn % LANES == 0, (q, bq, n, bn)
+    kp = lane_pad(k)
+    out_specs, out_shape, scratch = _topk_outputs(q, bq, kp)
+    kernel = functools.partial(_ivf_scan_kernel, pred=pred, k=k, bn=bn)
+    outd, outi = pl.pallas_call(
+        kernel,
+        grid=(q // bq, n // bn),
+        in_specs=[
+            pl.BlockSpec((bq, d), lambda qt, nb: (qt, 0)),
+            pl.BlockSpec((bq, w), lambda qt, nb: (qt, 0)),
+            pl.BlockSpec((bq, p), lambda qt, nb: (qt, 0)),
+            pl.BlockSpec((bn, d), lambda qt, nb: (nb, 0)),
+            pl.BlockSpec((1, bn), lambda qt, nb: (0, nb)),
+            pl.BlockSpec((w, bn), lambda qt, nb: (0, nb)),
+            pl.BlockSpec((1, bn), lambda qt, nb: (0, nb)),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+        name="ivf_scan_accum",
+    )(qvecs, qbms, probe, base, norms, bitmaps_wm, row_list)
     return outd, outi
 
 

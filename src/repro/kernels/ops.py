@@ -144,6 +144,51 @@ def masked_topk(qvecs, qbms, base, norms, bitmaps_wm, *, pred: int, k: int,
     return jnp.where(bad, -1, ids), jnp.where(bad, jnp.inf, dists)
 
 
+def _ivf_scan_xla(qvecs, qbms, probe, base, norms, bitmaps_wm, row_list, *,
+                  pred, k):
+    """XLA formulation of `mk.ivf_scan_accum`: `_masked_topk_xla` with the
+    kernel's probe mask ANDed in."""
+    scores = mk._scores(qvecs, base, norms[None, :])
+    mask = (mk._predicate_mask_block(bitmaps_wm, qbms, pred)
+            & mk._probe_mask_block(row_list[None, :], probe))
+    s = jnp.where(mask, scores, mk.PAD_SCORE)
+    ids = jnp.broadcast_to(
+        jnp.arange(base.shape[0], dtype=jnp.int32)[None, :], s.shape)
+    return _stable_topk(s, ids, k)
+
+
+@partial(jax.jit, static_argnames=("pred", "k", "bq", "bn", "interpret"))
+def ivf_scan_topk(qvecs, qbms, probe, base, norms, bitmaps_wm, row_list, *,
+                  pred: int, k: int, bq: int = mk.DEFAULT_BQ,
+                  bn: int = mk.DEFAULT_BN, interpret: bool | None = None):
+    """Filtered top-k over the rows of each query's probed IVF lists, by a
+    masked scan of the whole base. Returns (ids [Q,k] i32, dists [Q,k]).
+
+    As `masked_topk`, plus probe [Q, P] uint32 (bit ``l & 31`` of word
+    ``l >> 5`` set for each probed list l) and row_list [N] int32 (each
+    row's list, −1 for a row in no list). Ties go to the lower row id.
+    Off TPU the default is the XLA formulation; pass an explicit
+    `interpret` to force the Pallas kernel.
+    """
+    if interpret is None:
+        if not _on_tpu():
+            return _ivf_scan_xla(qvecs, qbms, probe, base, norms, bitmaps_wm,
+                                 row_list, pred=pred, k=k)
+        interpret = False
+    q = qvecs.shape[0]
+    n = base.shape[0]
+    qv, qb, bs, nm, bm, bq_eff, bn_eff = _pad_case(
+        qvecs, qbms, base, norms, bitmaps_wm, bq, bn)
+    pr = _pad_rows(probe, bq_eff)
+    rl = _pad_rows(row_list, bn_eff, fill=-1)[None, :]
+    outd, outi = mk.ivf_scan_accum(
+        qv, qb, pr, bs, nm, bm, rl, pred=pred, k=k, bq=bq_eff, bn=bn_eff,
+        interpret=interpret)
+    ids, dists = outi[:q, :k], outd[:q, :k]
+    bad = (ids < 0) | (ids >= n) | (dists >= mk.PAD_SCORE)
+    return jnp.where(bad, -1, ids), jnp.where(bad, jnp.inf, dists)
+
+
 @partial(jax.jit, static_argnames=("pred", "k", "bq", "bn", "interpret"))
 def masked_topk_multiblock(qvecs, qbms, base, norms, bitmaps_wm, *, pred: int,
                            k: int, bq: int = mk.DEFAULT_BQ,

@@ -372,3 +372,53 @@ def test_fused_live_xla_matches_kernel(pred, q, nd, kb, k, rng):
     _assert_bitwise(ops.fused_live_topk_select(*argsel, pred=pred, k=k),
                     ops.fused_live_topk_select(*argsel, pred=pred, k=k,
                                                interpret=True))
+
+
+# ---------------------------------------------------------------------------
+# probe-masked scan (`ivf_scan_topk`): kernel vs XLA twin vs a numpy oracle
+# ---------------------------------------------------------------------------
+
+def _probe_case(rng, q, n, nlist, nprobe, cap):
+    """The tie case plus IVF lists over its rows (capped lists drop rows)
+    and each query's probed lists as a bitmap; query 0 probes nothing."""
+    from repro.ann.ivf import pack_lists
+    from repro.ann.methods.ivf_gamma import row_lists
+
+    case = _tie_case(rng, q, n)
+    lists, _ = pack_lists(rng.integers(0, nlist, n), nlist, cap)
+    row_list = row_lists(lists, n)
+    probed = np.zeros((q, -(-nlist // 32) * 32), bool)
+    for i in range(1, q):
+        probed[i, rng.choice(nlist, nprobe, replace=False)] = True
+    words = (probed.reshape(q, -1, 32).astype(np.uint64)
+             << np.arange(32, dtype=np.uint64)).sum(-1).astype(np.uint32)
+    return case, jnp.asarray(words), jnp.asarray(row_list), probed
+
+
+@pytest.mark.parametrize("pred", [0, 1, 2])
+@pytest.mark.parametrize("q,n,nlist,k", [(3, 64, 5, 5), (7, 300, 37, 41),
+                                         (25, 1100, 70, 10)])
+def test_ivf_scan_xla_matches_kernel(pred, q, n, nlist, k, rng):
+    nprobe = max(1, nlist // 4)
+    cap = max(1, n // nlist // 2)             # every full list drops rows
+    (qv, qb, base, norms, bm), words, row_list, probed = _probe_case(
+        rng, q, n, nlist, nprobe, cap)
+    args = (qv, qb, words, base, norms, bm.T, row_list)
+    xla = ops.ivf_scan_topk(*args, pred=pred, k=k)
+    _assert_bitwise(xla, ops.ivf_scan_topk(*args, pred=pred, k=k,
+                                           interpret=True))
+    # oracle: the k smallest scores among the rows in a probed list that
+    # pass the predicate; rows a cap dropped (row_list −1) never qualify
+    rl = np.asarray(row_list)
+    assert (rl < 0).any()
+    ok = (np.asarray(ref.predicate_mask_ref(bm, qb, pred))
+          & (rl >= 0)[None, :] & probed[:, np.maximum(rl, 0)])
+    s = np.where(ok, np.asarray(ref._scores(qv, base, norms)), np.inf)
+    ids, dists = np.asarray(xla[0]), np.asarray(xla[1])
+    assert (ids[0] == -1).all()               # an empty probe word
+    for i in range(q):
+        got = ids[i][ids[i] >= 0]
+        assert ok[i, got].all()
+        assert got.size == min(k, int(ok[i].sum()))
+        np.testing.assert_array_equal(dists[i][:got.size],
+                                      np.sort(s[i])[:got.size])

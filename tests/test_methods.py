@@ -104,3 +104,140 @@ def test_prefilter_kernel_path_parity(tiny_index, tiny_queries):
                                  pred, qs.k, {})
         np.testing.assert_array_equal(ids_ref, ids_k)
         np.testing.assert_allclose(d_ref, d_k, rtol=1e-5, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# ivf_gamma: the probe-masked scan gives the per-query gather's answer
+# ---------------------------------------------------------------------------
+#
+# The reference gathers each query's probed lists, as ivf_gamma did before
+# it scanned. Vectors on a 1/4 grid make every score exact on both sides,
+# so the distances must agree bit for bit; only the order among equal
+# distances may differ (list order against row order).
+
+@pytest.fixture(scope="module")
+def grid_index(tiny_ds):
+    from repro.ann.dataset import ANNDataset
+    from repro.ann.index import FilteredIndex
+
+    ds = ANNDataset.from_packed("grid", np.round(tiny_ds.vectors * 4) / 4,
+                                tiny_ds.bitmaps, tiny_ds.universe)
+    fx = FilteredIndex(ds)
+    yield fx
+    fx.close()
+
+
+def _scan_and_gather(fx, index, setting, qs):
+    """(ids, dists) of the method and of the gather reference."""
+    from repro.ann.index import QueryBatch
+    from repro.ann.methods.ivf_gamma import probed_lists
+
+    m = CANDIDATE_METHODS["ivf_gamma"]
+    qv = np.round(qs.vectors * 4) / 4
+    scan = fx.run_method(m, setting, QueryBatch(qv, qs.bitmaps, qs.pred,
+                                                qs.k))
+    nprobe = min(4 * setting.search_dict["gamma"], index.centroids.shape[0])
+    probe = np.asarray(probed_lists(qv, index.centroids,
+                                    index.centroid_norms, nprobe))
+    ds = fx.ds
+    ids = np.full((qs.q, qs.k), -1, np.int64)
+    dists = np.full((qs.q, qs.k), np.inf, np.float32)
+    for qi in range(qs.q):
+        cand = index.lists[probe[qi]].ravel()
+        cand = cand[cand >= 0]
+        cand = cand[ds.matching_mask(qs.bitmaps[qi], qs.pred)[cand]]
+        v = ds.vectors[cand].astype(np.float64)
+        d = (v * v).sum(1) - 2 * v @ qv[qi].astype(np.float64)
+        top = np.argsort(d, kind="stable")[:qs.k]
+        ids[qi, :top.size], dists[qi, :top.size] = cand[top], d[top]
+    return scan, (ids, dists)
+
+
+def _assert_same_up_to_ties(a, b):
+    (ia, da), (ib, db) = a, b
+    np.testing.assert_array_equal(da, db)
+    for qi in range(ia.shape[0]):
+        kth = da[qi][-1]
+        sa = set(ia[qi][(ia[qi] >= 0) & (da[qi] < kth)].tolist())
+        sb = set(ib[qi][(ib[qi] >= 0) & (db[qi] < kth)].tolist())
+        assert sa == sb, qi
+        assert ((ia[qi] >= 0) == (ib[qi] >= 0)).all(), qi
+
+
+@pytest.mark.parametrize("ps_id", ["g1", "g4", "g8"])
+@pytest.mark.parametrize("pred", PREDICATES)
+def test_ivf_gamma_scan_matches_gather(grid_index, tiny_queries, pred,
+                                       ps_id):
+    from repro.ann.engine import resolve_setting
+
+    m = CANDIDATE_METHODS["ivf_gamma"]
+    setting = resolve_setting(m, ps_id)
+    index = grid_index.get_index(m, setting.build_dict)
+    scan, gather = _scan_and_gather(grid_index, index, setting,
+                                    tiny_queries[pred])
+    _assert_same_up_to_ties(scan, gather)
+    assert (scan[0] >= 0).any()
+
+
+@pytest.mark.parametrize("pred", PREDICATES)
+def test_ivf_gamma_scan_matches_gather_after_graft(grid_index, tiny_queries,
+                                                   pred):
+    """Compaction grafts the lists onto a remapped base: every third row
+    deleted, 40 new rows; the scan reads the grafted lists' row_list."""
+    from repro.ann.dataset import ANNDataset
+    from repro.ann.index import FilteredIndex
+    from repro.ann.methods.ivf_gamma import row_lists
+
+    m = CANDIDATE_METHODS["ivf_gamma"]
+    setting = m.param_settings()[-1]
+    old_ds = grid_index.ds
+    old = grid_index.get_index(m, setting.build_dict)
+    keep = np.nonzero(np.arange(old_ds.n) % 3 != 0)[0]
+    vec = np.concatenate([old_ds.vectors[keep],
+                          old_ds.vectors[:40] + np.float32(0.25)])
+    bms = np.concatenate([old_ds.bitmaps[keep], old_ds.bitmaps[:40]])
+    new_ds, order = ANNDataset.from_packed("grid2", vec, bms,
+                                           old_ds.universe, return_order=True)
+    pos = np.empty(order.size, np.int64)
+    pos[order] = np.arange(order.size)
+    old_to_new = np.full(old_ds.n, -1, np.int64)
+    old_to_new[keep] = pos[:keep.size]
+    grafted = m.graft_index(new_ds, old, old_ds, old_to_new,
+                            pos[keep.size:], setting.build_dict)
+    np.testing.assert_array_equal(grafted.row_list,
+                                  row_lists(grafted.lists, new_ds.n))
+    assert (grafted.row_list >= 0).all()
+    with FilteredIndex(new_ds) as fx:
+        fx.adopt_index(m, setting.build_dict, grafted)
+        scan, gather = _scan_and_gather(fx, grafted, setting,
+                                        tiny_queries[pred])
+    _assert_same_up_to_ties(scan, gather)
+
+
+@pytest.mark.parametrize("ps_id,nq", [("g1", 1), ("g8", 64), ("g4", 70)])
+def test_ivf_gamma_counts_scanned_and_probed_rows(grid_index, tiny_queries,
+                                                  ps_id, nq):
+    """Every query is scanned, one query alone too: `cand_rows` counts the
+    whole base a query, `probe_rows` the real rows of its probed lists."""
+    from repro.ann.engine import resolve_setting
+    from repro.ann.index import QueryBatch
+    from repro.ann.methods.ivf_gamma import probed_lists
+    from repro.ann.trace import Tracer
+
+    m = CANDIDATE_METHODS["ivf_gamma"]
+    setting = resolve_setting(m, ps_id)
+    idx = grid_index.get_index(m, setting.build_dict)
+    qs = tiny_queries[Predicate.OR]
+    qv = np.resize(qs.vectors, (nq, qs.vectors.shape[1]))
+    batch = QueryBatch(qv, np.resize(qs.bitmaps, (nq, qs.bitmaps.shape[1])),
+                       Predicate.OR, qs.k)
+    tr = Tracer(sample=1.0)
+    with tr.trace("search"):
+        grid_index.run_method(m, setting, batch)
+    c = tr.histograms()["search"]["counters"]
+    probe = np.asarray(probed_lists(qv, idx.centroids, idx.centroid_norms,
+                                    4 * setting.search_dict["gamma"]))
+    assert c["scan_queries"] == nq
+    assert c["cand_rows"] == nq * grid_index.ds.n
+    assert c["probe_rows"] == int(idx.list_len[probe].sum())
+    assert c["launches"] == -(-nq // 64)

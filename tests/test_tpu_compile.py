@@ -124,3 +124,17 @@ def test_fused_live_topk_select_compiles(one_chip, pred):
         _spec(sh, (), jnp.int32), _tomb_spec(sh), pred=pred, k=10,
         interpret=False)
     _assert_kernel(lowered, "fused_live_accum")
+
+
+# 128 IVF lists: four probe words
+@pytest.mark.parametrize("q", [25, 64])
+@pytest.mark.parametrize("pred", PREDS)
+def test_ivf_scan_topk_compiles(one_chip, pred, q):
+    sh = one_chip
+    lowered = ops.ivf_scan_topk.lower(
+        *_query_specs(sh, q), _spec(sh, (q, 4), jnp.uint32),
+        _spec(sh, (N_BASE, D), jnp.float32),
+        _spec(sh, (N_BASE,), jnp.float32),
+        _spec(sh, (W, N_BASE), jnp.uint32),
+        _spec(sh, (N_BASE,), jnp.int32), pred=pred, k=10, interpret=False)
+    _assert_kernel(lowered, "ivf_scan_accum")
