@@ -34,7 +34,7 @@ def readings(workload: str, seeds: list[int], seconds: float, *,
     run.device_check(int(cell["chips"]), require_tpu)
     run.enable_cache()
     dep = run.setup(cfg, mix, seeds[0], trace=False)
-    dev = reference.to_device(dep.corpus)
+    dev = run.reference_corpus(dep)
     mode = mode or cfg["control"]
     pool_methods = set(cfg["pool"])
     closed = mix["kind"] == "closed"

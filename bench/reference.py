@@ -6,7 +6,10 @@ artifact (`bench.router`):
 
 * exact masked top-k in plain `jax.numpy`, scores ‖v‖² − 2·q·v at
   `Precision.HIGHEST`, the predicate evaluated word by word over the
-  row-major bitmaps, one `lax.top_k` per query chunk;
+  row-major bitmaps, one `lax.top_k` per query chunk and block of rows
+  (the corpus is split into one contiguous block per chip of the cell;
+  blocks merge on the host in the order `lax.top_k` gives over the
+  whole corpus);
 * exact squared distances of any ids in float64 on the host;
 * selectivity as exact match counts over every row, on the device.
 
@@ -104,24 +107,46 @@ def _topk_fn():
 
 
 @dataclasses.dataclass
-class DeviceCorpus:
+class DeviceBlock:
+    """Rows [offset, offset + n) of the corpus on one device."""
+    offset: int
     vectors: object
     norms: object
     bitmaps: object
 
 
-def to_device(corpus) -> DeviceCorpus:
-    import jax.numpy as jnp
-
-    return DeviceCorpus(jnp.asarray(corpus.vectors),
-                        jnp.asarray(corpus.norms_sq),
-                        jnp.asarray(corpus.bitmaps))
+def block_bounds(n: int, blocks: int) -> list[int]:
+    """Row bounds of `blocks` contiguous blocks of nearly equal size."""
+    return [n * b // blocks for b in range(blocks + 1)]
 
 
-def topk(dev: DeviceCorpus, qv: np.ndarray, qb: np.ndarray, preds: np.ndarray,
-         k: int, *, mode: str = "highest", chunk: int = 32):
+def to_device(corpus, devices) -> list[DeviceBlock]:
+    """The corpus split into one contiguous block of rows per device of
+    `devices` (a device may repeat)."""
+    import jax
+
+    bounds = block_bounds(corpus.n, len(devices))
+    return [DeviceBlock(a, *(jax.device_put(x[a:b], d) for x in (
+                corpus.vectors, corpus.norms_sq, corpus.bitmaps)))
+            for a, b, d in zip(bounds[:-1], bounds[1:], devices)]
+
+
+def merge(ids: np.ndarray, scores: np.ndarray, k: int):
+    """[Q, C] candidates with global ids (−1 / +inf pads) -> the k best
+    of each row by (score, id): the order `lax.top_k` of the negated
+    scores gives over the whole corpus, where the lower index wins a
+    tie."""
+    key_id = np.where(ids >= 0, ids.astype(np.int64), np.iinfo(np.int64).max)
+    order = np.lexsort((key_id, scores), axis=1)[:, :k]
+    return (np.take_along_axis(ids, order, axis=1),
+            np.take_along_axis(scores, order, axis=1))
+
+
+def topk(dev: list[DeviceBlock], qv: np.ndarray, qb: np.ndarray,
+         preds: np.ndarray, k: int, *, mode: str = "highest", chunk: int = 32):
     """Exact masked top-k of every query: ([P, k] ids, [P, k] f32
-    scores, −1 / +inf pads). Queries run in chunks of one predicate."""
+    scores, −1 / +inf pads). Queries run in chunks of one predicate, each
+    chunk on every block of rows at once."""
     import jax
 
     fn = _topk_fn()
@@ -133,9 +158,14 @@ def topk(dev: DeviceCorpus, qv: np.ndarray, qb: np.ndarray, preds: np.ndarray,
         for s in range(0, rows.size, chunk):
             r = rows[s:s + chunk]
             pad = np.concatenate([r, np.repeat(r[-1:], chunk - r.size)])
-            i, d = fn(qv[pad], qb[pad], dev.vectors, dev.norms, dev.bitmaps,
-                      pred=int(pred), k=k, mode=mode)
-            i, d = jax.device_get((i, d))
+            parts = jax.device_get([
+                fn(qv[pad], qb[pad], b.vectors, b.norms, b.bitmaps,
+                   pred=int(pred), k=k, mode=mode) for b in dev])
+            i, d = merge(
+                np.concatenate([np.where(i >= 0, i + b.offset, -1)
+                                for (i, _), b in zip(parts, dev)],
+                               axis=1).astype(np.int32),
+                np.concatenate([d for _, d in parts], axis=1), k)
             ids[r], sc[r] = i[:r.size], d[:r.size]
     return ids, sc
 

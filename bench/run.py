@@ -11,8 +11,10 @@ router artifact, the limits of the comparison) and a traffic mix
 1. checks that the first JAX device is a TPU and that the cell's chips
    are there, and exits non-zero with no result otherwise;
 2. sets up: makes the corpus and the router artifact, hands them to the
-   program (`ANNDataset.from_packed`, `FilteredIndex`, `RouterService`),
-   builds the pool's indexes, draws the query pool from the mix's
+   program (`ANNDataset.from_packed`, `FilteredIndex`, `RouterService`;
+   with a configuration's `shards` > 1, `ShardedFilteredIndex` with one
+   row shard per chip of the cell and `ShardedRouterService`), builds the
+   pool's indexes (on every shard), draws the query pool from the mix's
    `pool_seed` and its order from `--seed`, and
    warms up every shape the window will use (`setup_s`);
 3. measures for `--seconds`: a closed loop of `RouterService.search`
@@ -97,6 +99,10 @@ def resolve(bench: dict, workload: str) -> tuple[dict, dict, dict]:
     cfgs = {c["name"]: c for c in bench["configs"]}
     cfg = load_json(os.path.join(ROOT, cfgs[cell["config"]]["file"]))
     mix = load_json(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
+    shards, chips = int(cfg.get("shards", 1)), int(cell["chips"])
+    if shards > 1 and shards != chips:
+        raise BenchError(f"configuration {cfg['name']!r} has {shards} shards, "
+                         f"one per chip, but the cell asks for {chips} chips")
     return cell, cfg, mix
 
 
@@ -308,15 +314,19 @@ class Deployment:
 
 
 def setup(cfg: dict, mix: dict, seed: int, trace: bool) -> Deployment:
+    """The deployment of a configuration. Its `shards` (absent: 1)
+    row-partitions the corpus, one shard per chip of the cell."""
     import jax
     import jax.numpy as jnp
 
     from repro.ann import registry
     from repro.ann.dataset import ANNDataset
     from repro.ann.index import FilteredIndex
-    from repro.ann.service import RouterService
+    from repro.ann.service import RouterService, ShardedRouterService
+    from repro.ann.sharded import ShardedFilteredIndex
     from repro.ann.trace import Tracer
 
+    shards = int(cfg.get("shards", 1))
     steps = {}
     t = time.monotonic()
     corpus = gen.make_corpus(cfg["corpus"], int(cfg["corpus_seed"]))
@@ -328,14 +338,22 @@ def setup(cfg: dict, mix: dict, seed: int, trace: bool) -> Deployment:
     if not np.array_equal(order, np.arange(ds.n)):
         raise BenchError("the program stores the rows in another order "
                          "than the benchmark's group order")
-    fx = FilteredIndex(ds)
-    jax.block_until_ready(fx.device.vectors)
+    if shards > 1:
+        fx = ShardedFilteredIndex(ds, shards, devices=jax.devices()[:shards])
+        parts = fx.shards
+    else:
+        fx = FilteredIndex(ds)
+        parts = [fx]
+    # each shard's own tensors: the sharded handle's `device` would upload
+    # a second, whole copy of the corpus
+    jax.block_until_ready([p.device.vectors for p in parts])
     steps["load_s"] = time.monotonic() - t
     t = time.monotonic()
     methods = {m: registry.get_method(m) for m in cfg["pool"]}
-    for m in methods.values():
-        for s in m.param_settings():
-            fx.get_index(m, s.build)
+    for p in parts:
+        for m in methods.values():
+            for s in m.param_settings():
+                p.get_index(m, s.build)
     steps["build_s"] = time.monotonic() - t
     t = time.monotonic()
     dev_bitmaps = jnp.asarray(corpus.bitmaps)
@@ -345,8 +363,9 @@ def setup(cfg: dict, mix: dict, seed: int, trace: bool) -> Deployment:
     del dev_bitmaps
     tracer = (Tracer(sample=1.0, recent_capacity=1, flight_capacity=1)
               if trace else None)
-    svc = RouterService(fx, router.to_program(art, ds.name), t=art.t,
-                        methods=methods, tracer=tracer)
+    service = ShardedRouterService if shards > 1 else RouterService
+    svc = service(fx, router.to_program(art, ds.name), t=art.t,
+                  methods=methods, tracer=tracer)
     pool = gen.query_pool(corpus, int(mix["pool_per_pred"]),
                           int(mix["pool_seed"]))
     steps["router_pool_s"] = time.monotonic() - t
@@ -685,6 +704,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     return result
 
 
+def reference_corpus(dep: Deployment):
+    """The reference's copy of the corpus: one block of rows on each chip
+    that holds a shard."""
+    import jax
+
+    return reference.to_device(
+        dep.corpus, jax.devices()[:int(dep.cfg.get("shards", 1))])
+
+
 def compare(dep: Deployment, ans, pool_methods, *, mode: str = "highest",
             answers_from_reference: bool = False, dev=None) -> dict:
     """The plain reference over the answered pool queries, then the
@@ -693,14 +721,15 @@ def compare(dep: Deployment, ans, pool_methods, *, mode: str = "highest",
     reference's device copy of the corpus, when the caller holds one."""
     cfg, pool, corpus = dep.cfg, dep.pool, dep.corpus
     k = int(cfg["k"])
-    dev = dev or reference.to_device(corpus)
+    dev = dev or reference_corpus(dep)
     u = np.unique(ans.pool_idx)
     ref_ids = np.full((pool.preds.size, k), -1, np.int32)
     ids_u, _ = reference.topk(dev, pool.vectors[u], pool.bitmaps[u],
                               pool.preds[u], k)
     ref_ids[u] = ids_u
-    sel = reference.match_counts(dev.bitmaps, pool.bitmaps[u],
-                                 pool.preds[u]) / corpus.n
+    sel = sum(reference.match_counts(b.bitmaps, pool.bitmaps[u],
+                                     pool.preds[u])
+              for b in dev) / corpus.n
     dec_u = router.decide(dep.art, sel, pool.preds[u],
                           margin=float(cfg["route_margin"]))
     ref_dec = [None] * pool.preds.size
