@@ -93,6 +93,21 @@ def fsync_path(path: str) -> None:
         os.close(fd)
 
 
+NORM_BLOCK_ROWS = 1 << 14
+
+
+def norms_sq(vectors: np.ndarray) -> np.ndarray:
+    """[N] float32 squared L2 norms, each row summed in float64. Taken in
+    blocks of `NORM_BLOCK_ROWS` rows, so the float64 temporary is one
+    block's and not the corpus's; a row's sum does not depend on the
+    rows beside it, so the result is the same bit for bit."""
+    out = np.empty(vectors.shape[0], dtype=np.float32)
+    for s in range(0, vectors.shape[0], NORM_BLOCK_ROWS):
+        blk = vectors[s:s + NORM_BLOCK_ROWS].astype(np.float64)
+        out[s:s + NORM_BLOCK_ROWS] = np.sum(np.square(blk, out=blk), axis=1)
+    return out
+
+
 @dataclasses.dataclass
 class ANNDataset:
     name: str
@@ -141,10 +156,13 @@ class ANNDataset:
             if k not in lookup:
                 lookup[k] = len(lookup)
             gid[i] = lookup[k]
-        order = np.argsort(gid, kind="stable")
-        vectors = vectors[order]
-        bitmaps = bitmaps[order]
-        gid = gid[order]
+        if np.all(gid[1:] >= gid[:-1]):
+            order = np.arange(n)      # already group-sorted: no copy
+        else:
+            order = np.argsort(gid, kind="stable")
+            vectors = vectors[order]
+            bitmaps = bitmaps[order]
+            gid = gid[order]
         g = len(lookup)
         group_bitmaps = np.zeros((g, bitmaps.shape[1]), dtype=np.uint32)
         # contiguous runs after stable sort
@@ -156,8 +174,7 @@ class ANNDataset:
             name=name, vectors=vectors, bitmaps=bitmaps, universe=universe,
             group_of=gid, group_bitmaps=group_bitmaps,
             group_start=starts, group_size=(ends - starts).astype(np.int32),
-            group_lookup=lookup,
-            norms_sq=np.sum(vectors.astype(np.float64) ** 2, axis=1).astype(np.float32),
+            group_lookup=lookup, norms_sq=norms_sq(vectors),
         )
         return (ds, order) if return_order else ds
 

@@ -1,36 +1,18 @@
-"""Distributed filtered-ANN search: corpus sharded over the mesh.
+"""Row partition and placement of a corpus sharded over devices.
 
-This is how the engine scales past one host/pod: the base vectors (and
-their label bitmaps) are sharded along the mesh `data` axis (composed with
-`pod` on multi-pod meshes), queries are replicated, each shard computes a
-*local* masked top-k with the same fused mask+distance+top-k hot loop, and
-an `all_gather` of the tiny [k] per-shard results is merged into the
-global top-k. Collective volume per query is `shards × k × 8` bytes —
-independent of corpus size, which is what makes the scheme viable at
-billion-vector scale.
-
-Two layers share this row-partition scheme:
-
-* `make_sharded_search` (here) — a single jitted shard_map over a
-  `launch.mesh` mesh; exact brute force only, minimum dispatch overhead.
-* `repro.ann.sharded.ShardedFilteredIndex` — host-orchestrated: one
-  owned `FilteredIndex` per shard (any registered method, per-shard
-  built indexes) with the cross-shard `ops.merge_topk` reduction. The
-  `shard_bounds`/`shard_devices` helpers below are its partition/
-  placement plumbing.
+`repro.ann.sharded.ShardedFilteredIndex` (and the live
+`ShardedLiveIndex`) split a corpus into contiguous row shards, one per
+device: `shard_bounds` gives the partition and `shard_devices` the
+placement. Each shard answers its own top-k and only the [Q, k]
+candidates meet for the merge, so the bytes that cross devices per query
+are `shards × k × 8`, whatever the corpus size.
 """
 
 from __future__ import annotations
 
-from functools import partial
-
 import numpy as np
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.ann import engine, topk
 
 
 def shard_bounds(n: int, n_shards: int) -> np.ndarray:
@@ -50,45 +32,3 @@ def shard_devices(n_shards: int) -> list:
     (every shard shares the single device of a CPU host)."""
     devs = jax.local_devices()
     return [devs[i % len(devs)] for i in range(n_shards)]
-
-
-def make_sharded_search(mesh, *, k: int, data_axes=("data",)):
-    """Build a jitted sharded brute-force filtered search for `mesh`.
-
-    data_axes: mesh axis name(s) the corpus rows shard over (e.g.
-    ("pod", "data") on the multi-pod mesh).
-    """
-    axes = data_axes if len(data_axes) > 1 else data_axes[0]
-
-    def local_search(qvecs, qbms, pred_idx, vectors, norms, bitmaps):
-        # local shard: fused mask + distance + top-k (Pallas kernel on TPU)
-        scores = topk.score_all(qvecs, vectors, norms)
-        mask = engine.mask_shared(bitmaps, qbms, pred_idx)
-        scores = jnp.where(mask, scores, topk.INF)
-        neg, idx = jax.lax.top_k(-scores, k)
-        # globalise ids with the shard row offset
-        offset = jnp.int32(0)
-        size = vectors.shape[0]
-        for i, ax in enumerate(data_axes):
-            stride = 1
-            for ax2 in data_axes[i + 1:]:
-                stride *= jax.lax.axis_size(ax2)
-            offset = offset + jax.lax.axis_index(ax) * stride
-        gids = jnp.where(jnp.isinf(neg), -1, idx + offset * size).astype(jnp.int32)
-        # gather every shard's [Q, k] candidates and merge
-        all_ids = jax.lax.all_gather(gids, axes, tiled=False)      # [S, Q, k]
-        all_neg = jax.lax.all_gather(neg, axes, tiled=False)
-        s = all_ids.shape[0]
-        all_ids = jnp.moveaxis(all_ids, 0, 1).reshape(gids.shape[0], s * k)
-        all_sc = -jnp.moveaxis(all_neg, 0, 1).reshape(gids.shape[0], s * k)
-        ids, _ = topk.topk_ids(all_sc, all_ids, k)
-        return ids
-
-    shard_axes = P(*data_axes) if len(data_axes) > 1 else P(data_axes[0])
-    fn = jax.shard_map(
-        local_search, mesh=mesh,
-        in_specs=(P(), P(), P(), shard_axes, shard_axes, shard_axes),
-        out_specs=P(),
-        check_vma=False,
-    )
-    return jax.jit(fn)

@@ -73,8 +73,6 @@ class DeviceData:
     group_bitmaps: jax.Array  # [G, W] uint32
     group_start: jax.Array    # [G] i32
     group_size: jax.Array     # [G] i32
-    group_centroids: jax.Array  # [G, d] f32
-    group_cnorms: jax.Array     # [G] f32
 
 
 def clear_caches() -> None:
@@ -154,20 +152,23 @@ def gather_chunk(n_cand: int, dim: int) -> int:
     return max(1, min(DEFAULT_QCHUNK, GATHER_BUDGET_BYTES // per_query))
 
 
+def pad_queries(n_queries: int, arrays, chunk: int = DEFAULT_QCHUNK):
+    """The per-query `arrays` (leading axis Q) padded to whole chunks of
+    `chunk` rows, the last query repeated, so every chunk has one static
+    shape."""
+    pad = (-n_queries) % chunk
+    if not pad:
+        return list(arrays)
+    return [np.concatenate([a, np.repeat(a[-1:], pad, axis=0)], axis=0)
+            for a in arrays]
+
+
 def padded_chunks(n_queries: int, arrays, chunk: int):
     """(real rows, padded parts) of each fixed-size query chunk of the
-    per-query `arrays` (leading axis Q); the tail chunk repeats its last
-    query up to `chunk` rows, so every chunk has one static shape."""
+    per-query `arrays`, padded as `pad_queries` pads them."""
+    padded = pad_queries(n_queries, arrays, chunk)
     for s in range(0, n_queries, chunk):
-        e = min(s + chunk, n_queries)
-        pad = chunk - (e - s)
-        parts = []
-        for a in arrays:
-            part = a[s:e]
-            if pad:
-                part = np.concatenate([part, np.repeat(part[-1:], pad, axis=0)], axis=0)
-            parts.append(part)
-        yield e - s, parts
+        yield min(chunk, n_queries - s), [a[s:s + chunk] for a in padded]
 
 
 def concat_chunks(outs: list):
@@ -280,6 +281,17 @@ class Method:
         ‖v‖² − 2·q·v, +inf where the id is −1)."""
         raise NotImplementedError
 
+    def search_resident(self, fx, index, qvecs: np.ndarray,
+                        qbms: np.ndarray, pred: Predicate, k: int,
+                        search_params: dict):
+        """`search` with the answer left on `fx`'s device: every launch
+        issued and none waited on. Returns ([Qp, k] int32 ids, [Qp, k]
+        float32 scores) as jax arrays, as `search` gives them, for
+        Qp >= Q (rows past Q are padding). A method without it keeps
+        this default, which `has_resident_search` tells apart."""
+        raise NotImplementedError(
+            f"method {self.name!r} has no search that stays on the device")
+
     def graft_index(self, new_ds: ANNDataset, old_index, old_ds: ANNDataset,
                     old_to_new: np.ndarray, new_rows: np.ndarray,
                     build_params: dict):
@@ -294,3 +306,9 @@ class Method:
         `build` — correct for every method, just linear in base size.
         """
         return None
+
+
+def has_resident_search(method: Method) -> bool:
+    """Whether `method` overrides `Method.search_resident`, i.e. has a
+    search that leaves its answer on the device."""
+    return type(method).search_resident is not Method.search_resident

@@ -170,8 +170,10 @@ class FilteredIndex:
             method names are resolved (`search("prefilter")` etc.).
         device: optional `jax.Device` to pin this handle's tensors to —
             the placement hook `ShardedFilteredIndex` uses to spread
-            shards across a multi-device host. Default: jax's default
-            device.
+            shards across a multi-device host. The dataset tensors are
+            committed to it, so every launch over them runs there (an
+            uncommitted array would be copied to the default device and
+            computed on it). Default: jax's default device.
     """
 
     def __init__(self, ds: ANNDataset, *, registry=None, device=None):
@@ -220,7 +222,7 @@ class FilteredIndex:
         self._check_open()
         if self._device is None:
             with self._device_scope():
-                self._device = _build_device_data(self.ds)
+                self._device = _build_device_data(self.ds, self._placement)
         return self._device
 
     def _device_scope(self):
@@ -345,10 +347,15 @@ class FilteredIndex:
 
     # ---- search ----------------------------------------------------------
     def run_method(self, method, setting: ParamSetting,
-                   batch: QueryBatch) -> tuple[np.ndarray, np.ndarray]:
+                   batch: QueryBatch, *,
+                   resident: bool = False) -> tuple[np.ndarray, np.ndarray]:
         """Raw single-method execution: ([Q, k] ids, [Q, k] ranking
         scores ‖v‖²−2·q·v). Building blocks for `search` and the bench
-        harness; most callers want `search`/`RouterService` instead."""
+        harness; most callers want `search`/`RouterService` instead.
+        `resident`: leave the answer on this handle's device, unwaited
+        (`Method.search_resident`, for a method that overrides it:
+        `engine.has_resident_search`; [Qp, k] jax arrays, Qp >= Q), for
+        a row-sharded handle's fan-out."""
         if batch.bitmaps.shape[1] != self.ds.bitmaps.shape[1]:
             raise ValueError(
                 f"QueryBatch bitmap width {batch.bitmaps.shape[1]} does "
@@ -359,8 +366,9 @@ class FilteredIndex:
                 f"dataset dim {self.ds.dim}")
         method = self._resolve_method(method)
         index = self.get_index(method, setting.build)
-        return method.search(self, index, batch.vectors, batch.bitmaps,
-                             batch.pred, batch.k, setting.search_dict)
+        search = method.search_resident if resident else method.search
+        return search(self, index, batch.vectors, batch.bitmaps,
+                      batch.pred, batch.k, setting.search_dict)
 
     def search(self, batch: QueryBatch, method,
                setting: ParamSetting | str | None = None) -> SearchResult:
@@ -388,25 +396,22 @@ class FilteredIndex:
             keys=self.keys_of(ids))
 
 
-def _build_device_data(ds: ANNDataset) -> DeviceData:
+def _build_device_data(ds: ANNDataset, device=None) -> DeviceData:
+    """The dataset's device tensors; committed to `device` when given."""
+    import jax
     import jax.numpy as jnp
 
-    g = ds.n_groups
-    cent = np.zeros((g, ds.dim), dtype=np.float32)
-    for j in range(g):
-        s, l = int(ds.group_start[j]), int(ds.group_size[j])
-        cent[j] = ds.vectors[s:s + l].mean(0)
-    bitmaps = jnp.asarray(ds.bitmaps)
+    put = jnp.asarray if device is None else (
+        lambda x: jax.device_put(x, device))
+    bitmaps = put(ds.bitmaps)
     return DeviceData(
-        vectors=jnp.asarray(ds.vectors),
-        norms=jnp.asarray(ds.norms_sq),
+        vectors=put(ds.vectors),
+        norms=put(ds.norms_sq),
         bitmaps=bitmaps,
         bitmaps_wm=bitmaps.T,
-        group_bitmaps=jnp.asarray(ds.group_bitmaps),
-        group_start=jnp.asarray(ds.group_start),
-        group_size=jnp.asarray(ds.group_size),
-        group_centroids=jnp.asarray(cent),
-        group_cnorms=jnp.asarray((cent ** 2).sum(1).astype(np.float32)),
+        group_bitmaps=put(ds.group_bitmaps),
+        group_start=put(ds.group_start),
+        group_size=put(ds.group_size),
     )
 
 

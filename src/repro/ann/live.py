@@ -1820,7 +1820,6 @@ class ShardedLiveIndex(_StableKeyMixin, _LabelClockMixin, _StageTimings):
         self._epoch = int(generation)
         self._epoch_readers: dict[int, int] = {}
         self._old_shards: dict[int, list] = {}
-        self._feature_fx: FilteredIndex | None = None
         self._compact_pool: ThreadPoolExecutor | None = None
         self._compacting: Future | None = None
         self._features = None
@@ -1872,24 +1871,6 @@ class ShardedLiveIndex(_StableKeyMixin, _LabelClockMixin, _StageTimings):
         with self._lock:
             return self._total_base + len(self._delta_loc)
 
-    @property
-    def feature_index(self) -> FilteredIndex:
-        """Full-base `FilteredIndex` on shard-0's device for the TPU
-        routing-feature kernels (lazy, like `ShardedFilteredIndex`)."""
-        self._check_open()
-        if self._base_ds is None:
-            raise RuntimeError(
-                f"ShardedLiveIndex({self._name!r}) has no sealed base yet")
-        if self._feature_fx is None:
-            self._feature_fx = FilteredIndex(
-                self._base_ds, registry=self._registry,
-                device=self._devices[0])
-        return self._feature_fx
-
-    @property
-    def device(self):
-        return self.feature_index.device
-
     def close(self) -> None:
         with self._lock:
             if self._closed:
@@ -1908,9 +1889,6 @@ class ShardedLiveIndex(_StableKeyMixin, _LabelClockMixin, _StageTimings):
                 for s in old:
                     s.close()
             self._old_shards.clear()
-            if self._feature_fx is not None:
-                self._feature_fx.close()
-                self._feature_fx = None
             self._features = None
         if self._pool is not None:
             self._pool.shutdown(wait=True)
@@ -2382,9 +2360,6 @@ class ShardedLiveIndex(_StableKeyMixin, _LabelClockMixin, _StageTimings):
                 self._epoch = old_epoch + 1
                 self._last_remap = remap
                 self._features = None
-                if self._feature_fx is not None:
-                    self._feature_fx.close()
-                    self._feature_fx = None
                 # replay: rows that didn't make the snapshot (and every
                 # row when the base fell below the shard count), carrying
                 # their stable keys; the WAL stays quiet — these rows'

@@ -77,14 +77,22 @@ class LabelNav(engine.Method):
         ]
 
     def build(self, ds: ANNDataset, build_params: dict):
-        return {"maxg": int(ds.group_size.max())}
+        """The largest group, and each group's centroid (the navigation
+        targets of AND/OR), kept on the host until a search uploads them:
+        a deployment without labelnav holds no [G, d] tensor."""
+        cent = np.zeros((ds.n_groups, ds.dim), dtype=np.float32)
+        for j in range(ds.n_groups):
+            s, l = int(ds.group_start[j]), int(ds.group_size[j])
+            cent[j] = ds.vectors[s:s + l].mean(0)
+        return {"maxg": int(ds.group_size.max()), "centroids": cent,
+                "cnorms": (cent ** 2).sum(1).astype(np.float32)}
 
     def index_arrays(self, index) -> dict:
-        return {"maxg": np.asarray(index["maxg"], dtype=np.int64)}
+        return {**index, "maxg": np.asarray(index["maxg"], dtype=np.int64)}
 
     def index_from_arrays(self, ds: ANNDataset, build_params: dict,
                           arrays: dict):
-        return {"maxg": int(arrays["maxg"])}
+        return {**arrays, "maxg": int(arrays["maxg"])}
 
     def search(self, fx, index, qvecs, qbms, pred: Predicate, k: int,
                search_params: dict):
@@ -108,9 +116,11 @@ class LabelNav(engine.Method):
         pgc = int(search_params["per_group_cap"])
         trace.count("cand_rows", nq * gc * pgc)
         pred_idx = jnp.int32(int(pred))
+        cent = fx.as_device(index["centroids"])
+        cnorms = fx.as_device(index["cnorms"])
         fn = lambda qv, qb: _search_sub(
             qv, qb, pred_idx, dev.group_bitmaps, dev.group_start,
-            dev.group_size, dev.group_centroids, dev.group_cnorms,
-            dev.vectors, dev.norms, group_cap=gc, per_group_cap=pgc, k=k)
+            dev.group_size, cent, cnorms, dev.vectors, dev.norms,
+            group_cap=gc, per_group_cap=pgc, k=k)
         chunk = engine.gather_chunk(gc * pgc, ds.dim)
         return engine.run_chunked(fn, nq, qvecs, qbms, chunk=chunk)

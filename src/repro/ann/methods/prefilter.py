@@ -30,6 +30,27 @@ def _search(qvecs, qbms, pred_idx, vectors, norms, bitmaps, *, k: int):
     return ids, -neg
 
 
+@partial(jax.jit, static_argnames=("pred", "k", "kernel"))
+def _scan_resident(qvecs, qbms, vectors, norms, bitmaps, bitmaps_wm, *,
+                   pred: int, k: int, kernel: bool):
+    """Every `engine.DEFAULT_QCHUNK`-query chunk of a padded batch in one
+    program: per chunk the launch `search` makes (the Pallas scan, or the
+    jnp reference), the chunks' answers concatenated on the device."""
+    c = engine.DEFAULT_QCHUNK
+    outs = []
+    for s in range(0, qvecs.shape[0], c):
+        qv, qb = qvecs[s:s + c], qbms[s:s + c]
+        if kernel:
+            from repro.kernels import ops
+
+            outs.append(ops.masked_topk(qv, qb, vectors, norms, bitmaps_wm,
+                                        pred=pred, k=k))
+        else:
+            outs.append(_search(qv, qb, jnp.int32(pred), vectors, norms,
+                                bitmaps, k=k))
+    return tuple(jnp.concatenate(part) for part in zip(*outs))
+
+
 class PreFilter(engine.Method):
     name = "prefilter"
 
@@ -50,14 +71,30 @@ class PreFilter(engine.Method):
                           arrays: dict):
         return None
 
+    def _use_kernel(self) -> bool:
+        return (jax.default_backend() == "tpu" if self.use_kernel is None
+                else self.use_kernel)
+
+    def search_resident(self, fx, index, qvecs, qbms, pred: Predicate,
+                        k: int, search_params: dict):
+        """One launch for the whole batch, padded to whole chunks, its
+        answer left on `fx`'s device."""
+        dev = fx.device
+        q = qvecs.shape[0]
+        trace.count("cand_rows", q * dev.vectors.shape[0])
+        qv, qb = engine.pad_queries(q, (qvecs, qbms))
+        with trace.launch(qv.shape[0], qv.shape[0] - q):
+            return _scan_resident(qv, qb, dev.vectors, dev.norms,
+                                  dev.bitmaps, dev.bitmaps_wm,
+                                  pred=int(Predicate(pred)), k=k,
+                                  kernel=self._use_kernel())
+
     def search(self, fx, index, qvecs, qbms, pred: Predicate, k: int,
                search_params: dict):
         dev = fx.device
         p = int(Predicate(pred))
         trace.count("cand_rows", qvecs.shape[0] * dev.vectors.shape[0])
-        use_kernel = (jax.default_backend() == "tpu"
-                      if self.use_kernel is None else self.use_kernel)
-        if use_kernel:
+        if self._use_kernel():
             from repro.kernels import ops
 
             fn = lambda qv, qb: ops.masked_topk(

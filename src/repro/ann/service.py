@@ -16,9 +16,9 @@ Scaling layers on top of the facade:
 
 * `ShardedRouterService` — the same routed pipeline over a
   `repro.ann.sharded.ShardedFilteredIndex`: the batch is routed once
-  (full-dataset features), each chosen (method, ps) group executes on
-  every shard in parallel, and the per-shard candidates reduce through
-  the `ops.merge_topk` kernel.
+  (selectivity counted on every shard's device and summed), each chosen
+  (method, ps) group executes on every shard, and the per-shard
+  candidates reduce through the `ops.merge_topk` kernel.
 * `AsyncBatchQueue` — serves *concurrent single-query callers*: callers
   `submit()` one query each and get a `Future`; a background worker
   coalesces pending requests into micro-batches (flushing on `max_batch`
@@ -361,11 +361,12 @@ class ShardedRouterService(RouterService):
 
     The routed pipeline is unchanged — and that is the point: the batch
     is routed **once** (one fused MLP forward over full-dataset features;
-    on TPU the feature kernels read the sharded handle's `feature_index`
-    tensors on shard-0's device), and only the execution of each chosen
-    (method, ps) group fans out: every shard searches its own row
-    partition in parallel and the per-shard candidates reduce through the
-    `ops.merge_topk` kernel inside the handle's `run_method`.
+    on TPU the selectivity kernel counts on every shard's own device and
+    the counts are summed, `features.device_match_counts`), and only the
+    execution of each chosen (method, ps) group fans out: every shard
+    searches its own row partition and the per-shard candidates reduce
+    through the `ops.merge_topk` kernel inside the handle's
+    `run_method`.
 
     Args:
         index: a `ShardedFilteredIndex` or `ShardedLiveIndex` (TypeError

@@ -4,37 +4,47 @@ This is the execution layer that scales the serving API past one device:
 the dataset is split into contiguous row shards (`ANNDataset.row_slice`),
 each shard is an ordinary owned `FilteredIndex` pinned to its own device
 (round-robin over the host's jax devices — `distributed.shard_devices`),
-and a batched search runs every shard in parallel before a cross-shard
-top-k merge (`ops.merge_topk`, the VMEM-accumulated Pallas reduction).
+and a batched search runs every shard before a cross-shard top-k merge
+(`ops.merge_topk`, the VMEM-accumulated Pallas reduction).
 
 The handle exposes the same `run_method`/`search`/`close` surface as
 `FilteredIndex`, so `RouterService` (and its `ShardedRouterService`
 subclass) dispatches through it unchanged: a batch is routed **once** —
-one fused MLP forward over full-dataset features — and only the chosen
-(method, ps) execution fans out per shard. Shard-local ids are globalised
-with each shard's row offset (row slices preserve row order), which is
-what lets the merge kernel treat per-shard candidates as disjoint.
+one fused MLP forward over features whose selectivity counts are summed
+over the shards' own devices (`features.device_match_counts`) — and only
+the chosen (method, ps) execution fans out per shard. Shard-local ids are
+globalised with each shard's row offset (row slices preserve row order),
+which is what lets the merge treat per-shard candidates as disjoint. No
+device holds more than its own shard.
 
-Relation to `repro.ann.distributed`: `make_sharded_search` is the
-single-jit shard_map formulation of the same row partition for the exact
-brute-force scan inside one mesh; `ShardedFilteredIndex` is the
-host-orchestrated generalisation that serves *every* registered method
-(each shard runs its own built index) and composes with the async
-micro-batch queue in `repro.ann.service`.
+How a (method, ps) group fans out follows from the method:
+
+* a method with a search that stays on the device (one that overrides
+  `Method.search_resident`: the exact scan) is issued on every shard
+  before the host waits on any; each shard's [Q, k] candidates stay on
+  its device, only they (Q·k·8 bytes a shard) are copied to the first
+  shard's device, and one program there globalises and merges them:
+  the host waits once;
+* any other method runs `FilteredIndex.run_method` on every shard over
+  a thread pool (each shard waits on its own launches) and the host
+  merges the numpy candidates through the same kernel.
 """
 
 from __future__ import annotations
 
 import time
 from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.ann import trace
 from repro.ann.dataset import ANNDataset
 from repro.ann.distributed import shard_bounds, shard_devices
-from repro.ann.engine import (ParamSetting, pop_stage_timings,
-                              resolve_setting, stage_add)
+from repro.ann.engine import (ParamSetting, has_resident_search,
+                              pop_stage_timings, resolve_setting, stage_add)
 from repro.ann.index import (FilteredIndex, QueryBatch, SearchResult,
                              exact_distances)
 
@@ -68,11 +78,33 @@ def merge_candidates(ids: np.ndarray, raw: np.ndarray,
     """Reduce [S, Q, K] globalised candidates to the global top-k through
     the `ops.merge_topk` kernel. Returns ([Q, k] i32 ids with −1 pad,
     [Q, k] f32 scores with +inf at −1)."""
-    import jax.numpy as jnp
-
     from repro.kernels import ops
 
     gids, graw = ops.merge_topk(jnp.asarray(ids), jnp.asarray(raw), k=k)
+    return np.asarray(gids), np.asarray(graw)
+
+
+@partial(jax.jit, static_argnames="k")
+def _globalise_merge(ids, raw, offsets, *, k: int):
+    """[S] per-shard ([Q, K] local ids, [Q, K] scores), on one device ->
+    the global top-k, ids offset by each shard's first row."""
+    from repro.kernels import ops
+
+    ids, raw = jnp.stack(ids), jnp.stack(raw)
+    ids = jnp.where(ids >= 0, ids + offsets[:, None, None], -1)
+    return ops.merge_topk(ids, raw, k=k)
+
+
+def merge_resident(parts, offsets: np.ndarray, k: int, device):
+    """Copy each shard's candidates (jax arrays on its own device, or
+    numpy) to `device` and globalise and merge them there in one program;
+    the host waits once, for the merged answer. Returns numpy
+    ([Q, k] int32 global ids with −1 pad, [Q, k] float32 scores, +inf at
+    −1)."""
+    ids = [jax.device_put(i, device) for i, _ in parts]
+    raw = [jax.device_put(r, device) for _, r in parts]
+    off = jax.device_put(np.asarray(offsets, dtype=np.int32), device)
+    gids, graw = _globalise_merge(ids, raw, off, k=k)
     return np.asarray(gids), np.asarray(graw)
 
 
@@ -83,8 +115,8 @@ class ShardedFilteredIndex:
 
     Args:
         ds: the full dataset. Row-partitioned; the parent is kept for
-            routing features and exact distances (host arrays are shared
-            views — no vector copy).
+            the host-side routing features (host arrays are shared views
+            — no vector copy); nothing of it goes to a device whole.
         n_shards: number of row shards (ignored when `bounds` is given).
         bounds: optional explicit shard boundaries [S+1] (ragged shards);
             defaults to `distributed.shard_bounds(ds.n, n_shards)`.
@@ -92,9 +124,10 @@ class ShardedFilteredIndex:
             round-robin over the host's devices (all shards land on the
             single device of a CPU host — still correct, just serial).
         registry: optional `MethodRegistry` forwarded to every shard.
-        parallel: fan shard execution out over a thread pool (jax
-            releases the GIL during device compute, so per-device shards
-            overlap). Serial when False or with a single shard.
+        parallel: fan a non-resident method's shard execution out over
+            a thread pool (jax releases the GIL during device compute, so
+            per-device shards overlap). Serial when False or with a
+            single shard.
 
     Raises:
         ValueError: if bounds are not a strictly increasing cover of
@@ -116,6 +149,7 @@ class ShardedFilteredIndex:
         self.bounds = bounds
         if devices is None:
             devices = shard_devices(bounds.size - 1)
+        self.devices = list(devices[:bounds.size - 1])
         self.shards = [
             FilteredIndex(ds.row_slice(int(s), int(e),
                                        name=f"{ds.name}/shard{i}"),
@@ -127,7 +161,6 @@ class ShardedFilteredIndex:
             max_workers=len(self.shards),
             thread_name_prefix=f"shard-{ds.name}") if self._parallel
             else None)
-        self._feature_fx: FilteredIndex | None = None
         self._features = None        # routing-feature cache (full dataset)
         self._closed = False
 
@@ -141,13 +174,10 @@ class ShardedFilteredIndex:
         return self._closed
 
     def close(self) -> None:
-        """Close every shard handle (and the feature handle, if built) and
-        shut the dispatch pool down. Idempotent."""
+        """Close every shard handle and shut the dispatch pool down.
+        Idempotent."""
         for fx in self.shards:
             fx.close()
-        if self._feature_fx is not None:
-            self._feature_fx.close()
-            self._feature_fx = None
         if self._pool is not None:
             self._pool.shutdown(wait=True)
             self._pool = None
@@ -165,55 +195,64 @@ class ShardedFilteredIndex:
             raise RuntimeError(
                 f"ShardedFilteredIndex({self.ds.name!r}) is closed")
 
-    # ---- routing-feature surface (parent dataset, shard-0 device) -------
-    @property
-    def feature_index(self) -> FilteredIndex:
-        """Owned `FilteredIndex` over the *full* dataset on shard-0's
-        device — backs the TPU feature kernels (batched selectivity needs
-        the whole bitmap tensor; per-shard bitmaps would under-count).
-        Built lazily: CPU feature paths never touch it."""
-        self._check_open()
-        if self._feature_fx is None:
-            self._feature_fx = FilteredIndex(
-                self.ds, registry=self._registry,
-                device=self.shards[0]._placement)
-        return self._feature_fx
-
-    @property
-    def device(self):
-        """Full-dataset device tensors (routing-feature path only; shard
-        execution uses each shard's own tensors)."""
-        return self.feature_index.device
-
     # ---- search ----------------------------------------------------------
-    def _map_shards(self, fn):
-        if self._pool is not None:
-            return list(self._pool.map(fn, self.shards))
-        return [fn(fx) for fx in self.shards]
+    def _resolve_method(self, method):
+        if isinstance(method, str):
+            from repro.ann import registry as registry_mod
+
+            return (self._registry
+                    or registry_mod.default_registry()).get(method)
+        return method
 
     def run_method(self, method, setting: ParamSetting,
                    batch: QueryBatch) -> tuple[np.ndarray, np.ndarray]:
         """Raw sharded execution of one (method, setting) over the batch.
 
-        Every shard runs `FilteredIndex.run_method` on its own tensors
-        (in parallel across devices), shard-local ids are globalised with
-        the shard row offsets, and the [S, Q, k] candidates reduce to the
-        global top-k through `ops.merge_topk`.
+        Every shard runs `FilteredIndex.run_method` on its own tensors,
+        shard-local ids are globalised with the shard row offsets, and
+        the [S, Q, k] candidates reduce to the global top-k through
+        `ops.merge_topk` (see the module docstring for the two ways a
+        group fans out; the method decides).
 
         Returns: ([Q, k] int32 global ids with −1 pad, [Q, k] float32
         ranking scores ‖v‖² − 2·q·v with +inf at −1) — identical contract
         to `FilteredIndex.run_method`, so the serving layer can't tell
         the difference.
 
-        Per-shard wall seconds accumulate on the calling thread's stage
-        slate (`shard{j}_s`, plus `shard_max_s` — the straggler that
-        bounds fan-out latency, which a sum across shards would hide —
-        and `merge_s`), drained by `pop_stage_timings()`.  Under an
-        active trace each shard's run is a `shard` child span attached
-        across the pool's threads.
+        Under an active trace the group is one `fanout` span, from the
+        first shard's launch until the merged answer is on the host,
+        with a `merge` child. Seconds accumulate on the calling thread's
+        stage slate (`shard{j}_s`, `shard_max_s` — the straggler that
+        bounds fan-out latency — and `merge_s`), drained by
+        `pop_stage_timings()`; a resident fan-out waits once, so each of
+        its shards reads the seconds until the merged answer was in hand.
         Raises: RuntimeError if closed; ValueError on shape mismatch.
         """
         self._check_open()
+        method = self._resolve_method(method)
+        with trace.span("fanout", shards=self.n_shards, q=batch.q):
+            if has_resident_search(method):
+                return self._fan_out_resident(method, setting, batch)
+            return self._fan_out_host(method, setting, batch)
+
+    def _fan_out_resident(self, method, setting, batch):
+        t0 = time.perf_counter()
+        parts = [fx.run_method(method, setting, batch, resident=True)
+                 for fx in self.shards]
+        t_merge = time.perf_counter()
+        rows = parts[0][0].shape[0]
+        with trace.span("merge", shards=len(parts)):
+            with trace.launch(rows, rows - batch.q):
+                ids, raw = merge_resident(parts, self.bounds[:-1], batch.k,
+                                          self.devices[0])
+        t1 = time.perf_counter()
+        for j in range(len(parts)):
+            stage_add(f"shard{j}_s", t1 - t0)
+        stage_add("shard_max_s", t1 - t0)
+        stage_add("merge_s", t1 - t_merge)
+        return ids[:batch.q], raw[:batch.q]
+
+    def _fan_out_host(self, method, setting, batch):
         parent = trace.current()
         times = [0.0] * len(self.shards)
 
@@ -257,14 +296,9 @@ class ShardedFilteredIndex:
         the whole fan-out + cross-shard merge.
         """
         self._check_open()
+        method = self._resolve_method(method)
         if not isinstance(setting, ParamSetting):
-            from repro.ann import registry as registry_mod
-
-            m = (method if not isinstance(method, str)
-                 else (self._registry
-                       or registry_mod.default_registry()).get(method))
-            setting = resolve_setting(m, setting)
-            method = m
+            setting = resolve_setting(method, setting)
         t0 = time.perf_counter()
         ids, raw = self.run_method(method, setting, batch)
         dt = time.perf_counter() - t0

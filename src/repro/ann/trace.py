@@ -244,11 +244,14 @@ class _LaunchCtx(_SpanCtx):
         return s
 
 
-def launch(slots: int, pad_slots: int = 0) -> _SpanCtx:
-    """A `launch` span: one device launch plus the host's wait for its
-    result, counting ``launches`` (1), ``slots`` (the query rows it
-    launched) and ``pad_slots`` (how many of them are padding)."""
-    return _LaunchCtx(LAUNCH, {"launches": 1, "slots": int(slots),
+def launch(slots: int, pad_slots: int = 0, launches: int = 1) -> _SpanCtx:
+    """A `launch` span: device launches (one, or `launches` issued
+    together, one per device of a sharded handle) plus the host's wait
+    for their results, counting ``launches``, ``slots`` (the query rows
+    launched, over all of them) and ``pad_slots`` (how many of those are
+    padding)."""
+    return _LaunchCtx(LAUNCH, {"launches": int(launches),
+                               "slots": int(slots),
                                "pad_slots": int(pad_slots)})
 
 

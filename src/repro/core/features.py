@@ -306,6 +306,25 @@ def batch_selectivity(ds: ANNDataset, qbms: np.ndarray,
     return np.maximum(counts, 0.0) / max(live.n_live, 1)
 
 
+def device_match_counts(fx, qbms: np.ndarray, pred: Predicate) -> np.ndarray:
+    """[Q] int64 exact predicate match counts over the sealed rows of the
+    handle `fx`, counted by the selectivity kernel on the device that
+    holds them. A row-sharded handle (`fx.shards`) counts on every shard's
+    own device over that shard's own bitmaps: every launch is issued
+    before the host waits on any, and the integer counts are summed, so
+    no device ever needs more than its shard. One `launch` span covers
+    the launches and the wait."""
+    from repro.kernels import ops
+
+    parts = getattr(fx, "shards", None) or [fx]
+    # qbms is per-request: each launch uploads it to its shard's device
+    # (the handle's as_device cache would pin every batch forever)
+    with trace.launch(qbms.shape[0] * len(parts), launches=len(parts)):
+        counts = [ops.selectivity(qbms, p.device.bitmaps_wm, pred=int(pred))
+                  for p in parts]
+        return sum(np.asarray(c).astype(np.int64) for c in counts)
+
+
 def _base_selectivity(ds: ANNDataset, qbms: np.ndarray,
                       pred: Predicate, *, fx=None) -> np.ndarray:
     """Sealed-base selectivity fractions (over `ds.n`); see
@@ -314,19 +333,10 @@ def _base_selectivity(ds: ANNDataset, qbms: np.ndarray,
 
     pred = Predicate(pred)
     if jax.default_backend() == "tpu":
-        import jax.numpy as jnp
-
         from repro.ann.index import default_index
-        from repro.kernels import ops
 
-        # qbms is per-request: upload directly (the handle's as_device
-        # cache would pin every batch forever)
-        with trace.launch(qbms.shape[0]):
-            counts = ops.selectivity(
-                jnp.asarray(qbms), (fx or default_index(ds)).device.bitmaps_wm,
-                pred=int(pred))
-            counts = np.asarray(counts)
-        return counts.astype(np.float64) / ds.n
+        return device_match_counts(fx or default_index(ds), qbms,
+                                   pred).astype(np.float64) / ds.n
 
     # queries repeat label sets heavily (they are drawn from base vectors):
     # evaluate unique bitmaps once and scatter the results back

@@ -91,11 +91,32 @@ def _stable_topk(all_d, all_i, k):
     return jnp.where(bad, -1, out_i), jnp.where(bad, jnp.inf, -neg)
 
 
+XLA_SCORE_ROWS = 512       # base rows per product of the off-TPU scores
+
+
+def xla_scores(qvecs, base, norms_row):
+    """`mk._scores` off the TPU, in fixed blocks of `XLA_SCORE_ROWS` base
+    rows. The CPU backend rounds a row's dot product differently at
+    different row counts, so one product shape for every block makes a
+    row's score independent of how many rows share its scan (a shard, a
+    delta segment, the whole corpus)."""
+    n, d = base.shape
+    pad = (-n) % XLA_SCORE_ROWS
+    blocks = jnp.pad(base, ((0, pad), (0, 0))).reshape(
+        -1, XLA_SCORE_ROWS, d)
+    dots = jax.lax.map(lambda b: jax.lax.dot_general(
+        qvecs, b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32), blocks)      # [NB, Q, rows]
+    dots = jnp.moveaxis(dots, 0, 1).reshape(qvecs.shape[0], -1)[:, :n]
+    return norms_row.astype(jnp.float32) - 2.0 * dots
+
+
 def _masked_topk_xla(qvecs, qbms, base, norms, bitmaps_wm, *, pred, k):
     """XLA formulation of the masked scan: same score expression and
     predicate word-loop as the kernel, one stable top_k over the rows in
     ascending-id order (= the kernel's block fold order)."""
-    scores = mk._scores(qvecs, base, norms[None, :])
+    scores = xla_scores(qvecs, base, norms[None, :])
     mask = mk._predicate_mask_block(bitmaps_wm, qbms, pred)
     s = jnp.where(mask, scores, mk.PAD_SCORE)
     ids = jnp.broadcast_to(
@@ -148,7 +169,7 @@ def _ivf_scan_xla(qvecs, qbms, probe, base, norms, bitmaps_wm, row_list, *,
                   pred, k):
     """XLA formulation of `mk.ivf_scan_accum`: `_masked_topk_xla` with the
     kernel's probe mask ANDed in."""
-    scores = mk._scores(qvecs, base, norms[None, :])
+    scores = xla_scores(qvecs, base, norms[None, :])
     mask = (mk._predicate_mask_block(bitmaps_wm, qbms, pred)
             & mk._probe_mask_block(row_list[None, :], probe))
     s = jnp.where(mask, scores, mk.PAD_SCORE)
@@ -291,7 +312,7 @@ def _fused_live_xla(qvecs, qbms, ci, cd, dvec, dnorms, dbm_wm, di, *,
     predicate loop as `mk.fused_live_accum`; candidates laid out
     base-first then delta rows in mirror order (= the kernel's fold order)
     under one stable top_k."""
-    scores = mk._scores(qvecs, dvec, dnorms[None, :])
+    scores = xla_scores(qvecs, dvec, dnorms[None, :])
     mask = mk._predicate_mask_block(dbm_wm, qbms, pred)
     s = jnp.where(mask & (di >= 0)[None, :], scores, mk.PAD_SCORE)
     return _stable_topk(jnp.concatenate([cd, s], axis=1),

@@ -19,24 +19,26 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 out = {}
 
 # ---- 1. sharded filtered ANN search == exact ground truth ----
+# the exact scan of a ShardedFilteredIndex over four devices: each
+# shard's scan left on its device, merged on the first one
 from repro.data.ann_synth import DatasetSpec, synthesize, make_queries
-from repro.ann import distributed
+from repro.ann.index import QueryBatch
 from repro.ann.predicates import Predicate
-from repro.ann.dataset import ground_truth_topk
+from repro.ann.sharded import ShardedFilteredIndex
 spec = DatasetSpec("t", 1600, 24, 40, 6, 8, 1.3, 2.0, 0.5, 0.3, 7)
 ds = synthesize(spec)
 mesh = jax.make_mesh((4, 2), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
-fn = distributed.make_sharded_search(mesh, k=10, data_axes=("data",))
 match = 0
-for pred in (Predicate.EQUALITY, Predicate.AND, Predicate.OR):
-    qs = make_queries(ds, pred, 8, seed=5)
-    ids = np.asarray(fn(qs.vectors, qs.bitmaps, jnp.int32(int(pred)),
-                        ds.vectors, ds.norms_sq, ds.bitmaps))
-    for i in range(8):
-        want = set(qs.ground_truth[i][qs.ground_truth[i] >= 0].tolist())
-        got = set(ids[i][ids[i] >= 0].tolist())
-        match += got == want
+with ShardedFilteredIndex(ds, 4, devices=jax.devices()[:4]) as sfx:
+    for pred in (Predicate.EQUALITY, Predicate.AND, Predicate.OR):
+        qs = make_queries(ds, pred, 8, seed=5)
+        ids = sfx.search(QueryBatch(qs.vectors, qs.bitmaps, pred, 10),
+                         "prefilter").ids
+        for i in range(8):
+            want = set(qs.ground_truth[i][qs.ground_truth[i] >= 0].tolist())
+            got = set(ids[i][ids[i] >= 0].tolist())
+            match += got == want
 out["ann_match"] = match
 
 # ---- 2. sharded train step runs and loss decreases ----

@@ -83,8 +83,10 @@ def test_two_indexes_never_share_state(tiny_ds):
         m = registry_mod.get_method("labelnav")
         ia = fa.get_index(m, m.param_settings()[0].build)
         ib = fb.get_index(m, m.param_settings()[0].build)
-        assert ia == {"maxg": int(tiny_ds.group_size.max())}
-        assert ib == {"maxg": int(other.group_size.max())}
+        assert ia["maxg"] == int(tiny_ds.group_size.max())
+        assert ib["maxg"] == int(other.group_size.max())
+        assert ia["centroids"].shape == (tiny_ds.n_groups, tiny_ds.dim)
+        assert ib["centroids"].shape == (other.n_groups, other.dim)
         # same dataset, two handles: still no sharing (owned, not global)
         with FilteredIndex(tiny_ds) as fa2:
             assert fa2.device.vectors is not fa.device.vectors

@@ -138,3 +138,35 @@ def test_ivf_scan_topk_compiles(one_chip, pred, q):
         _spec(sh, (W, N_BASE), jnp.uint32),
         _spec(sh, (N_BASE,), jnp.int32), pred=pred, k=10, interpret=False)
     _assert_kernel(lowered, "ivf_scan_accum")
+
+
+@pytest.mark.parametrize("pred", PREDS)
+def test_resident_scan_compiles(one_chip, pred, monkeypatch):
+    """One shard's exact scan of a 256-query batch for a row-sharded
+    handle: the four 64-query chunks' Pallas scans in one program."""
+    from repro.ann.methods.prefilter import _scan_resident
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    sh = one_chip
+    lowered = _scan_resident.lower(
+        *_query_specs(sh, 256), _spec(sh, (N_BASE, D), jnp.float32),
+        _spec(sh, (N_BASE,), jnp.float32),
+        _spec(sh, (N_BASE, W), jnp.uint32),
+        _spec(sh, (W, N_BASE), jnp.uint32), pred=pred, k=10, kernel=True)
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert "%masked_topk_accum" in text
+
+
+def test_resident_merge_compiles(one_chip, monkeypatch):
+    """The merge of four shards' [256, 10] candidates on one chip: ids
+    offset by each shard's first row, then the merge kernel."""
+    from repro.ann.sharded import _globalise_merge
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    sh = one_chip
+    lowered = _globalise_merge.lower(
+        [_spec(sh, (256, 10), jnp.int32)] * 4,
+        [_spec(sh, (256, 10), jnp.float32)] * 4,
+        _spec(sh, (4,), jnp.int32), k=10)
+    _assert_kernel(lowered, "merge_topk_accum")
