@@ -1,15 +1,22 @@
 """IVF coarse quantizer: k-means build (numpy, offline) + padded list layout.
 
 Lists are stored as a dense padded `[nlist, max_list]` int32 matrix (−1
-padding) — the gather-friendly TPU layout (no pointer chasing; a probe is a
-contiguous row gather followed by an MXU distance block).
+padding), and each row's list as `row_list`. The methods that search an
+IVF (ivf_gamma, postfilter, sieve's fallback) choose a query's probed
+lists with `probed_lists` and score them by a masked scan of the whole
+base, which reads the probed lists as a bitmap (`probe_words`) against
+`row_list`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.ann import topk
 
 
 @dataclasses.dataclass
@@ -18,9 +25,41 @@ class IVFIndex:
     centroid_norms: np.ndarray  # [nlist] float32
     lists: np.ndarray           # [nlist, max_list] int32, −1 pad
     list_len: np.ndarray        # [nlist] int32
-    # [N] int32, each row's list (−1: none); filled by ivf_gamma, whose
-    # scan reads it, from `lists` and never persisted
+    # [N] int32, each row's list (−1: none); filled by the methods whose
+    # scan reads it (`with_row_list`), from `lists`, and never persisted
     row_list: np.ndarray | None = None
+
+
+def row_lists(lists: np.ndarray, n: int) -> np.ndarray:
+    """[n] int32: the list that holds each row, −1 for a row no list holds
+    (one a `max_list_cap` dropped)."""
+    out = np.full(n, -1, dtype=np.int32)
+    rows_c, _ = np.nonzero(lists >= 0)
+    out[lists[lists >= 0]] = rows_c
+    return out
+
+
+def with_row_list(index: IVFIndex, n: int) -> IVFIndex:
+    return dataclasses.replace(index, row_list=row_lists(index.lists, n))
+
+
+def probed_lists(qvecs, centroids, cnorms, nprobe: int):
+    """[Q, nprobe] ids of the lists each query probes: its nearest
+    centroids."""
+    cd = topk.score_all(qvecs, centroids, cnorms)
+    return jax.lax.top_k(-cd, nprobe)[1]
+
+
+def probe_words(probe, nlist: int):
+    """[Q, ceil(nlist / 32)] uint32 bitmap of each query's probed lists
+    (`probe` [Q, nprobe]): bit ``l & 31`` of word ``l >> 5`` set for each
+    probed list l, the layout `ops.ivf_scan_topk` reads."""
+    nq = probe.shape[0]
+    hit = jnp.zeros((nq, -(-nlist // 32) * 32), jnp.uint32).at[
+        jnp.arange(nq)[:, None], probe].set(1)
+    return jnp.sum(hit.reshape(nq, -1, 32)
+                   << jnp.arange(32, dtype=jnp.uint32), axis=2,
+                   dtype=jnp.uint32)
 
 
 def kmeans(x: np.ndarray, k: int, iters: int = 8, seed: int = 0,

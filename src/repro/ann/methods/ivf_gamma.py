@@ -18,38 +18,17 @@ a chunk of queries too, and gathered rows score on the VPU.
 
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.ann import engine, topk, trace
+from repro.ann import engine, trace
 from repro.ann.dataset import ANNDataset
-from repro.ann.ivf import IVFIndex, build_ivf, graft_ivf
+from repro.ann.ivf import (IVFIndex, build_ivf, graft_ivf, probe_words,
+                           probed_lists, row_lists, with_row_list)
 from repro.ann.predicates import Predicate
 from repro.kernels import ops
-
-
-def probed_lists(qvecs, centroids, cnorms, nprobe: int):
-    """[Q, nprobe] ids of the lists each query probes: its nearest
-    centroids."""
-    cd = topk.score_all(qvecs, centroids, cnorms)
-    return jax.lax.top_k(-cd, nprobe)[1]
-
-
-def row_lists(lists: np.ndarray, n: int) -> np.ndarray:
-    """[n] int32: the list that holds each row, −1 for a row no list holds
-    (one a `max_list_cap` dropped)."""
-    out = np.full(n, -1, dtype=np.int32)
-    rows_c, _ = np.nonzero(lists >= 0)
-    out[lists[lists >= 0]] = rows_c
-    return out
-
-
-def _with_row_list(index: IVFIndex, n: int) -> IVFIndex:
-    return dataclasses.replace(index, row_list=row_lists(index.lists, n))
 
 
 @partial(jax.jit, static_argnames=("nprobe", "k", "pred"))
@@ -58,13 +37,8 @@ def _scan(qvecs, qbms, centroids, cnorms, list_len, row_list,
     """Filtered top-k over each query's `nprobe` nearest lists, by a masked
     scan of the whole base. Also returns each query's rows in its probed
     lists (list padding left out)."""
-    nq, nlist = qvecs.shape[0], centroids.shape[0]
     probe = probed_lists(qvecs, centroids, cnorms, nprobe)
-    hit = jnp.zeros((nq, -(-nlist // 32) * 32), jnp.uint32).at[
-        jnp.arange(nq)[:, None], probe].set(1)
-    words = jnp.sum(hit.reshape(nq, -1, 32)
-                    << jnp.arange(32, dtype=jnp.uint32), axis=2,
-                    dtype=jnp.uint32)                          # [Q, P]
+    words = probe_words(probe, centroids.shape[0])             # [Q, P]
     ids, dists = ops.ivf_scan_topk(qvecs, qbms, words, vectors, norms,
                                    bitmaps_wm, row_list, pred=pred, k=k)
     return ids, dists, jnp.sum(list_len[probe], axis=1)
@@ -82,7 +56,7 @@ class IVFGamma(engine.Method):
         ]
 
     def build(self, ds: ANNDataset, build_params: dict) -> IVFIndex:
-        return _with_row_list(
+        return with_row_list(
             build_ivf(ds.vectors, int(build_params.get("nlist", 128)),
                       seed=13), ds.n)
 
@@ -103,7 +77,7 @@ class IVFGamma(engine.Method):
                     old_ds: ANNDataset, old_to_new, new_rows, build_params):
         if old_index.centroids.shape[0] == 0 or new_ds.n == 0:
             return None
-        return _with_row_list(
+        return with_row_list(
             graft_ivf(old_index, new_ds.vectors, old_to_new), new_ds.n)
 
     def search(self, fx, index: IVFIndex, qvecs, qbms, pred: Predicate,

@@ -10,7 +10,9 @@ lists** for the `n_lists` most frequent labels (dense padded rows):
 * AND/EQ  — scan the *shortest* materialised posting row among the query's
             labels, verifying the full predicate per candidate (classic
             inverted-index intersection);
-* miss    — fall back to Post-filter on a shared global IVF.
+* miss    — fall back to Post-filter on a shared global IVF
+            (`postfilter.search_ivf`: 8 probed lists, one masked scan
+            per 64 queries).
 
 `index_budget`/`hist_pct` (paper Table 3) map to the materialised-label
 fraction and `list_cap`; `ef_search` maps to the fallback k′.
@@ -26,8 +28,8 @@ import numpy as np
 
 from repro.ann import engine, topk, trace
 from repro.ann.dataset import ANNDataset
-from repro.ann.ivf import IVFIndex, build_ivf
-from repro.ann.methods.postfilter import _search as _post_search
+from repro.ann.ivf import IVFIndex, build_ivf, row_lists, with_row_list
+from repro.ann.methods.postfilter import search_ivf
 from repro.ann.predicates import Predicate
 
 
@@ -83,7 +85,7 @@ class Sieve(engine.Method):
             rows[r, :len(ids)] = ids
             truncated[r] = len(members[l]) > cap
             row_of[l] = r
-        ivf = build_ivf(ds.vectors, 128, seed=29)
+        ivf = with_row_list(build_ivf(ds.vectors, 128, seed=29), ds.n)
         return {"rows": rows, "row_of": row_of, "row_len":
                 np.array([len(members[l]) for l in mat_labels] or [0]),
                 "ivf": ivf, "cap": cap}
@@ -107,7 +109,8 @@ class Sieve(engine.Method):
         ivf = IVFIndex(centroids=arrays["ivf_centroids"],
                        centroid_norms=arrays["ivf_centroid_norms"],
                        lists=arrays["ivf_lists"],
-                       list_len=arrays["ivf_list_len"])
+                       list_len=arrays["ivf_list_len"],
+                       row_list=row_lists(arrays["ivf_lists"], ds.n))
         return {"rows": arrays["rows"], "row_of": row_of,
                 "row_len": arrays["row_len"], "ivf": ivf,
                 "cap": int(arrays["cap"])}
@@ -165,18 +168,7 @@ class Sieve(engine.Method):
                 chunk=chunk)
 
         if miss_idx.size:
-            ivf = index["ivf"]
-            kprime = int(search_params.get("ef_search", 200))
-            nprobe = min(8, ivf.centroids.shape[0])
-            trace.count("cand_rows",
-                        miss_idx.size * nprobe * ivf.lists.shape[1])
-            fn = lambda qv, qb: _post_search(
-                qv, qb, pred_idx, fx.as_device(ivf.centroids),
-                fx.as_device(ivf.centroid_norms), fx.as_device(ivf.lists),
-                dev.vectors, dev.norms, dev.bitmaps,
-                nprobe=nprobe, kprime=kprime, k=k)
-            out[miss_idx], out_d[miss_idx] = engine.run_chunked(
-                fn, miss_idx.size, qvecs[miss_idx], qbms[miss_idx],
-                chunk=engine.gather_chunk(nprobe * ivf.lists.shape[1],
-                                          qvecs.shape[1]))
+            out[miss_idx], out_d[miss_idx] = search_ivf(
+                fx, index["ivf"], qvecs[miss_idx], qbms[miss_idx], pred, k,
+                nprobe=8, kprime=int(search_params.get("ef_search", 200)))
         return out, out_d

@@ -112,6 +112,21 @@ def xla_scores(qvecs, base, norms_row):
     return norms_row.astype(jnp.float32) - 2.0 * dots
 
 
+def scores(qvecs, base, norms):
+    """[Q, N] ranking scores ‖v‖² − 2·q·v at HIGHEST, by XLA: the
+    kernels' score expression as one product on the TPU, `xla_scores`'s
+    fixed blocks off it."""
+    if _on_tpu():
+        return mk._scores(qvecs, base, norms[None, :])
+    return xla_scores(qvecs, base, norms[None, :])
+
+
+def probe_mask(probe, row_list):
+    """bool [Q, N]: row n lies in one of query q's probed IVF lists.
+    probe [Q, P] and row_list [N] as `ivf_scan_topk` takes them."""
+    return mk._probe_mask_block(row_list[None, :], probe)
+
+
 def _masked_topk_xla(qvecs, qbms, base, norms, bitmaps_wm, *, pred, k):
     """XLA formulation of the masked scan: same score expression and
     predicate word-loop as the kernel, one stable top_k over the rows in

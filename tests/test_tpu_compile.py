@@ -170,3 +170,23 @@ def test_resident_merge_compiles(one_chip, monkeypatch):
         [_spec(sh, (256, 10), jnp.float32)] * 4,
         _spec(sh, (4,), jnp.int32), k=10)
     _assert_kernel(lowered, "merge_topk_accum")
+
+
+@pytest.mark.parametrize("pred", PREDS)
+def test_postfilter_scan_compiles(one_chip, pred, monkeypatch):
+    """Post-filter ef2000's chunk function: 64 queries, 32 of 128 lists
+    probed, k′ = 2000: the probe-masked Pallas scan, then the [Q, N]
+    product and the k′ rank count in XLA."""
+    from repro.ann.methods.postfilter import scan
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    sh = one_chip
+    nlist = 128
+    lowered = scan.lower(
+        *_query_specs(sh, 64), _spec(sh, (nlist, D), jnp.float32),
+        _spec(sh, (nlist,), jnp.float32), _spec(sh, (nlist,), jnp.int32),
+        _spec(sh, (N_BASE,), jnp.int32), _spec(sh, (N_BASE, D), jnp.float32),
+        _spec(sh, (N_BASE,), jnp.float32),
+        _spec(sh, (W, N_BASE), jnp.uint32), nprobe=32, kprime=2000, k=10,
+        pred=pred)
+    _assert_kernel(lowered, "ivf_scan_accum")
